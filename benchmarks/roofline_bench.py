@@ -12,18 +12,17 @@ Two sections, one record schema (``repro.telemetry.profile.roofline_record``):
     client solve) analyzed standalone, both the pure-XLA reference and the
     ``repro.kernels.dispatch`` path the engine actually calls.
 
-The attainable ceiling comes from ``repro.roofline.model`` (TPU v5e): on a
-CPU runner the achieved fraction reads as a tiny number — the artifact is a
-*model* comparison there, pinned for shape, not for silicon. Headline
-records refresh the tracked ``BENCH_roofline.json`` when not in smoke mode;
-schema checked by scripts/check_roofline_artifact.py.
+The attainable ceiling is the running device's, from the ``device_kind``
+table in ``repro.roofline.model``; a device that is not in the table (a CPU
+runner) gets no attainable ceiling and no roofline share, only the HLO
+counts and its own timings. Records go to ``benchmarks/out/`` only; schema
+checked by scripts/check_roofline_artifact.py.
 
     TELEMETRY_SMOKE=1 PYTHONPATH=src python -m benchmarks.run --only roofline_bench
 """
 
 from __future__ import annotations
 
-import json
 import os
 
 import jax
@@ -34,7 +33,7 @@ from repro.api import build, specs
 from repro.core import engine, quantization
 from repro.kernels import dispatch
 from repro.kernels.client_solve.ref import client_solve_ref
-from repro.roofline.model import HBM_BW, PEAK_FLOPS_BF16
+from repro.roofline.model import device_peaks
 from repro.telemetry import EngineTracer, analyze_jitted, roofline_record
 
 SMOKE = os.environ.get("TELEMETRY_SMOKE", "0") == "1"
@@ -66,33 +65,26 @@ def _engine_records():
         for rec in tracer.roofline_records():
             rec = {"case": label, **rec}
             records.append(rec)
-            if "error" not in rec:
-                emit(
-                    f"roofline/engine/{label}/{rec['label']}",
-                    (rec["seconds_per_call"] or 0.0) * 1e6,
-                    f"bound={rec['bound']};"
-                    f"ai={rec['arithmetic_intensity']:.2f};"
-                    f"frac={rec['achieved_fraction']:.2e}",
-                )
+            emit(
+                f"roofline/engine/{label}/{rec['label']}",
+                (rec["seconds_per_call"] or 0.0) * 1e6, _derived(rec),
+            )
     return records
+
+
+def _derived(rec) -> str:
+    return (f"bound={rec['bound']};ai={rec['arithmetic_intensity']:.2f};"
+            f"frac={rec['achieved_fraction']}")
 
 
 def _analyze_callable(label: str, fn, *args):
     """roofline_record for one jitted callable: AOT HLO analysis + fastest
-    timed call. Analysis failures become {"error": ...} records — a cost
-    model must not kill the suite (same contract as EngineTracer)."""
+    timed call. A lowering or compile error propagates."""
     jitted = jax.jit(fn)
-    try:
-        cost = analyze_jitted(jitted, *args)
-    except Exception as e:
-        return {"label": label, "error": f"{type(e).__name__}: {e}"}
+    cost = analyze_jitted(jitted, *args)
     _, us = timed(lambda: jitted(*args), iters=3)
     rec = roofline_record(label, cost, us * 1e-6)
-    emit(
-        f"roofline/kernel/{label}", us,
-        f"bound={rec['bound']};ai={rec['arithmetic_intensity']:.2f};"
-        f"frac={rec['achieved_fraction']:.2e}",
-    )
+    emit(f"roofline/kernel/{label}", us, _derived(rec))
     return rec
 
 
@@ -138,26 +130,22 @@ def _kernel_records():
 
 
 def main():
+    kind = jax.devices()[0].device_kind
+    peaks = device_peaks(kind)
     results = {
         "config": {
             "smoke": SMOKE,
             "rounds": ROUNDS,
             "backend": jax.default_backend(),
+            "device_kind": kind,
             "resolved_pallas_backend": dispatch.resolve_backend("pallas"),
-            "peak_flops_bf16": PEAK_FLOPS_BF16,
-            "hbm_bw": HBM_BW,
+            "peak_flops_bf16": None if peaks is None else peaks.flops_bf16,
+            "hbm_bw": None if peaks is None else peaks.hbm_bw,
         },
         "engine": _engine_records(),
         "kernels": _kernel_records(),
     }
     save_json("roofline_bench.json", results)
-    if not SMOKE:
-        root = os.path.abspath(
-            os.path.join(os.path.dirname(__file__), "..",
-                         "BENCH_roofline.json")
-        )
-        with open(root, "w") as f:
-            json.dump(results, f, indent=2, default=float)
     return results
 
 

@@ -19,6 +19,8 @@ import time
 
 import jax
 
+from repro.launch.compile_cache import enable_compile_cache
+
 SUITES = ("bits_table", "paper_fig1", "paper_fig2", "bits_ablation", "privacy_demo", "kernel_bench", "matfree_scaling", "comm_tradeoff", "solver_frontier", "lm_workload", "async_frontier", "roofline_bench")
 
 
@@ -27,6 +29,7 @@ def main() -> None:
     ap.add_argument("--only", choices=SUITES, default=None)
     args = ap.parse_args()
 
+    enable_compile_cache()
     # the paper-repro suites run in f64 like the paper's CPU experiments
     jax.config.update("jax_enable_x64", True)
 

@@ -94,8 +94,7 @@ python scripts/check_async_artifact.py BENCH_async_frontier.json
 # domains present) by the telemetry CLI, plus the roofline suite at tiny
 # rounds with its artifact schema-checked — the trace format, the
 # diagnostics stream, and the HLO-cost roofline plumbing cannot silently
-# rot. The tracked repo-root BENCH_roofline.json is validated against the
-# same schema so a stale refresh fails here too.
+# rot.
 python -m repro.api examples/specs/traced_quickstart.json \
     --out benchmarks/out/traced_quickstart_runresult.json
 python -m repro.telemetry validate benchmarks/out/traced_quickstart_trace.json \
@@ -108,4 +107,3 @@ python -m repro.telemetry validate benchmarks/out/traced_events_trace.json \
 python -m repro.telemetry summarize benchmarks/out/traced_quickstart_trace.json
 TELEMETRY_SMOKE=1 BENCH_ROUNDS=4 python -m benchmarks.run --only roofline_bench
 python scripts/check_roofline_artifact.py benchmarks/out/roofline_bench.json
-python scripts/check_roofline_artifact.py BENCH_roofline.json

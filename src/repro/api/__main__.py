@@ -3,9 +3,10 @@
     python -m repro.api SPEC.json [--out RESULT.json]
     python -m repro.api --template          # print a default spec to edit
 
-Loads the spec, auto-enables ``jax_enable_x64`` when the partition asks for
-float64, runs it through ``repro.api.run``, prints a short summary, and
-writes the RunResult JSON to ``--out`` (or the spec's
+Loads the spec, turns on the persistent compilation cache
+(``repro.launch.compile_cache``), auto-enables ``jax_enable_x64`` when the
+partition asks for float64, runs it through ``repro.api.run``, prints a
+short summary, and writes the RunResult JSON to ``--out`` (or the spec's
 ``telemetry.save_path``). Exercised by ``scripts/ci.sh`` on
 ``examples/specs/quickstart.json`` so the CLI and the JSON schema cannot
 silently rot.
@@ -41,6 +42,9 @@ def main(argv=None) -> int:
     with open(args.spec) as f:
         raw = json.load(f)
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     # float64 partitions need x64 — flip it before any jax arrays exist.
     if (raw.get("partition") or {}).get("dtype") == "float64":
         import jax

@@ -329,22 +329,26 @@ def run(
 
     with _span(tracer, "init", solver=solver.name):
         state = solver.init(obj, data, key, x0)
+    # The dataset is an argument of every jitted call, never a closure: a
+    # closed-over array is embedded in the program as a constant, which at
+    # a few GB of client data exhausts host memory during compilation.
     if part is None:
-        step1 = lambda s: solver.step(s, obj, data)
+        step1 = lambda s, d: solver.step(s, obj, d)
         carry = state
     else:
         n = data.n_clients
 
-        def step1(c):
+        def step1(c, d):
             s, pkey = c
             pkey, sub = participation_lib.split_round(pkey)
             mask = participation_lib.round_mask(sub, n, part)
-            s, m = solver.step(s, obj, data, mask=mask)
+            s, m = solver.step(s, obj, d, mask=mask)
             return (s, pkey), m
 
         carry = (state, part.init_key())
     if mode == "host":
-        carry, metrics = _host_loop(step1, carry, rounds, timings, tracer)
+        carry, metrics = _host_loop(step1, carry, data, rounds, timings,
+                                    tracer)
     else:
         if donate:
             # init() may alias caller arrays (the PRNG key, x0); donating
@@ -352,7 +356,7 @@ def run(
             # caller.
             carry = jax.tree.map(jnp.copy, carry)
         carry, metrics = _scan_blocks(
-            step1, carry, rounds, block_size, donate, timings, tracer
+            step1, carry, data, rounds, block_size, donate, timings, tracer
         )
     return (carry[0] if part is not None else carry), metrics
 
@@ -383,13 +387,13 @@ def _timed(call, n_rounds: int, timings, tracer=None, label="step"):
     return out
 
 
-def _host_loop(step1, state, rounds: int, timings=None, tracer=None):
-    """The historical driver, verbatim: jit one step, iterate on the host."""
+def _host_loop(step1, state, data, rounds: int, timings=None, tracer=None):
+    """The historical driver: jit one step, iterate on the host."""
     jstep = jax.jit(step1)
-    _profile(tracer, "host_step", jstep, state)
+    _profile(tracer, "host_step", jstep, state, data)
     history = []
     for _ in range(rounds):
-        state, m = _timed(lambda: jstep(state), 1, timings, tracer,
+        state, m = _timed(lambda: jstep(state, data), 1, timings, tracer,
                           "host_step")
         history.append(m)
     return state, jax.tree.map(lambda *xs: jnp.stack(xs), *history)
@@ -409,19 +413,20 @@ def _concat_metrics(chunks):
     return jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *chunks)
 
 
-def _scan_blocks(step1, state, rounds: int, block_size, donate: bool,
+def _scan_blocks(step1, state, data, rounds: int, block_size, donate: bool,
                  timings=None, tracer=None):
-    def block(s, length):
-        return jax.lax.scan(lambda c, _: step1(c), s, None, length=length)
+    def block(s, d, length):
+        return jax.lax.scan(lambda c, _: step1(c, d), s, None, length=length)
 
     jblock = jax.jit(
-        block, static_argnums=1, donate_argnums=(0,) if donate else ()
+        block, static_argnums=2, donate_argnums=(0,) if donate else ()
     )
     chunks = []
     for n in _block_plan(rounds, block_size):
         label = f"scan_block[{n}r]"
-        _profile(tracer, label, jblock, state, n)
-        state, m = _timed(lambda: jblock(state, n), n, timings, tracer, label)
+        _profile(tracer, label, jblock, state, data, n)
+        state, m = _timed(lambda: jblock(state, data, n), n, timings, tracer,
+                          label)
         chunks.append(m)
     return state, _concat_metrics(chunks)
 

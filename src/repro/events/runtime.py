@@ -323,17 +323,11 @@ def _barrier_run(
     curv_shape = np.asarray(state.curv).shape
     curv_dtype = np.asarray(state.curv).dtype
 
-    # When the cohort IS the fleet, the materialized data is round-invariant
-    # and we close over it — the identical jit trace to engine.run(mode=
-    # "host"), which is what makes the sync degeneracy bit-exact (XLA folds
-    # closed-over constants differently from traced arguments, so the
-    # general data-as-argument step is only tolerance-equal).
-    if cohort == n:
-        jstep = jax.jit(lambda s: solver.step(s, obj, data0))
-        run_step = lambda s, d: jstep(s)
-    else:
-        jstep = jax.jit(lambda s, d: solver.step(s, obj, d))
-        run_step = jstep
+    # The cohort's data is an argument of the step, as in the engine's
+    # compiled rounds: when the cohort IS the fleet this is the identical jit
+    # trace to engine.run(mode="host"), which is what makes the sync
+    # degeneracy bit-exact.
+    jstep = jax.jit(lambda s, d: solver.step(s, obj, d))
 
     history: List[Any] = []
     round_time_s: List[float] = []
@@ -360,7 +354,7 @@ def _barrier_run(
             step=jnp.asarray(r, jnp.int32),
         )
         with _span(tracer, "dispatch", label="barrier_step", rounds=1):
-            st2, m = run_step(st, data)
+            st2, m = jstep(st, data)
         x, y, k = st2.x, st2.y, st2.key
         cache.scatter(ids, np.asarray(st2.lam), np.asarray(st2.comm), r)
         history.append(jax.tree.map(np.asarray, m))
@@ -400,7 +394,9 @@ def _barrier_run(
         downlink_bits_total=down_totals,
         contributors=contributors,
         n_server_steps=rounds,
-        simulated_time_s=t_total,
+        # summed as netsim.simulate_rounds sums (Python's compensated sum),
+        # not as the running clock above, so the two totals agree exactly
+        simulated_time_s=float(sum(round_time_s)),
         peak_state_bytes=peak,
         n_spills=cache.n_spills,
         n_restores=cache.n_restores,

@@ -2,10 +2,18 @@
 
 Each FL client must apply (H_i + (alpha+rho) I)^{-1} to its ADMM right-hand
 side every round. At paper scale (d ≤ 267) the whole damped Hessian tile fits
-VMEM with room to spare, so the TPU-native design (DESIGN.md §3.4) keeps
-A_i resident in VMEM and runs a fixed-iteration conjugate-gradient loop whose
-matvec is a (d × d)·(d,) MXU contraction — no HBM traffic inside the loop,
-one grid step per client.
+VMEM with room to spare, so the TPU-native design keeps A_i resident in VMEM
+and runs a fixed-iteration conjugate-gradient loop whose matvec is a
+(1 × d)·(d × d) MXU contraction at full f32 precision — no HBM traffic
+inside the loop, one grid step per client.
+
+Block layout: the client dim is squeezed out of every block (``None``), so
+the kernel sees an A tile of ``(d, d)`` and rhs/solution tiles of ``(1, d)``
+taken from ``(n, 1, d)`` arrays — each block's last two dims span the whole
+array dims, which is what the TPU compiler requires of a block that is not
+(8, 128)-aligned. A is double-buffered across grid steps, so VMEM holds
+2·d²·4 bytes of it: ``ops.MAX_DIM`` is the largest padded d that fits the
+default scoped VMEM.
 
 The damping (alpha + rho) bounds the condition number, so a modest fixed
 iteration count reaches float32 solve accuracy (tests sweep d, dtype, and
@@ -25,27 +33,32 @@ from jax.experimental import pallas as pl
 
 
 def _kernel(a_ref, b_ref, x_ref, *, iters: int, damping: float):
-    A = a_ref[0].astype(jnp.float32)  # (d, d) resident in VMEM
     b = b_ref[...].astype(jnp.float32)  # (1, d)
 
     def matvec(p):  # (1,d) @ (d,d) on the MXU; A is symmetric
+        A = a_ref[...].astype(jnp.float32)  # (d, d), read from VMEM
         return jax.lax.dot_general(
-            p, A, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            p, A, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
         ) + damping * p
+
+    def dot(u, v):  # (1, 1): keeps every value 2-D for the TPU lowering
+        return jnp.sum(u * v, axis=1, keepdims=True)
 
     x = jnp.zeros_like(b)
     r = b
     p = r
-    rs = jnp.sum(r * r)
+    rs = dot(r, r)
 
     def body(_, carry):
         x, r, p, rs = carry
         ap = matvec(p)
-        denom = jnp.sum(p * ap)
+        denom = dot(p, ap)
         alpha = jnp.where(denom > 0, rs / jnp.maximum(denom, 1e-30), 0.0)
         x = x + alpha * p
         r = r - alpha * ap
-        rs_new = jnp.sum(r * r)
+        rs_new = dot(r, r)
         beta = jnp.where(rs > 0, rs_new / jnp.maximum(rs, 1e-30), 0.0)
         p = r + beta * p
         return x, r, p, rs_new
@@ -65,14 +78,13 @@ def client_solve_cg(
     """(n, d) solutions of (A_i + damping·I) x = b_i, one grid step/client."""
     n, d, _ = A.shape
     kernel = functools.partial(_kernel, iters=iters, damping=damping)
-    return pl.pallas_call(
+    row = pl.BlockSpec((None, 1, d), lambda i: (i, 0, 0))
+    x = pl.pallas_call(
         kernel,
         grid=(n,),
-        in_specs=[
-            pl.BlockSpec((1, d, d), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, d), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, d), b.dtype),
+        in_specs=[pl.BlockSpec((None, d, d), lambda i: (i, 0, 0)), row],
+        out_specs=row,
+        out_shape=jax.ShapeDtypeStruct((n, 1, d), b.dtype),
         interpret=interpret,
-    )(A, b)
+    )(A, b[:, None, :])
+    return x[:, 0, :]

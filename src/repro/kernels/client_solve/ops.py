@@ -19,6 +19,11 @@ import jax.numpy as jnp
 from repro.kernels.client_solve.client_solve import client_solve_cg
 
 LANE = 128
+# Largest padded d whose double-buffered (d, d) f32 A tile fits the default
+# scoped VMEM of a TPU v5e (2·1280²·4 B ≈ 13 MB; 1408 is refused by the
+# compiler). Larger problems solve eq. 9 with backend="reference" or
+# hessian_repr="matfree".
+MAX_DIM = 1280
 
 
 def _pad_up(d: int) -> int:
@@ -36,6 +41,12 @@ def client_solve(
         interpret = dispatch.default_interpret()
     n, d, _ = A.shape
     dp = _pad_up(d)
+    if dp > MAX_DIM and not interpret:
+        raise ValueError(
+            f"client_solve keeps a ({dp}, {dp}) f32 tile in VMEM, over the "
+            f"{MAX_DIM} limit; solve eq. 9 with backend='reference' or "
+            "hessian_repr='matfree' at this d"
+        )
     if dp != d:
         pad = dp - d
         A = jnp.pad(A, ((0, 0), (0, pad), (0, pad)))

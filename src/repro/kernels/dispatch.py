@@ -119,20 +119,14 @@ def _load(path: str) -> Callable:
 
 def resolve_impl(name: str, backend: str = "auto") -> tuple:
     """Resolve ``backend`` and return ``(callable, resolved_flavor)`` from
-    the registry. Pallas flavors degrade to the reference if the kernel
-    fails to import (the 'jnp reference as last resort' leg) — the returned
-    flavor says which implementation the caller actually got, so call sites
-    know whether to pass kernel-only kwargs like ``interpret``."""
+    the registry; the flavor tells call sites whether to pass kernel-only
+    kwargs like ``interpret``. A kernel that fails to import raises: the
+    reference never stands in for a kernel the backend asked for."""
     if name not in _REGISTRY:
         raise KeyError(f"unknown kernel {name!r}; have {registered_kernels()}")
-    entry = _REGISTRY[name]
     resolved = resolve_backend(backend)
-    if use_pallas(resolved):
-        try:
-            return _load(entry["pallas"]), resolved
-        except ImportError:
-            resolved = "reference"
-    return _load(entry["reference"]), resolved
+    flavor = "pallas" if use_pallas(resolved) else "reference"
+    return _load(_REGISTRY[name][flavor]), resolved
 
 
 def get_impl(name: str, backend: str = "auto") -> Callable:

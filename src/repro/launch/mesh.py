@@ -12,9 +12,7 @@ jax device state (the dry-run sets XLA_FLAGS before any jax import).
     client axes come from ``fed.client_axes`` (usually ``('data',)``) and
     the remaining axes form each client's private tensor-parallel mesh.
 
-Axis-type tagging (Auto) is applied only on jax versions that expose
-``jax.sharding.AxisType``; older versions construct untyped meshes with
-identical semantics for our usage.
+Every mesh axis is typed ``AxisType.Auto``.
 """
 
 from __future__ import annotations
@@ -25,10 +23,9 @@ from repro.sharding.specs import CLIENT_AXIS
 
 
 def _make_mesh(shape, axes):
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

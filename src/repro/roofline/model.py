@@ -1,4 +1,5 @@
-"""Three-term roofline model from compiled dry-run artifacts (TPU v5e target).
+"""Per-chip peaks by device kind, and the three-term roofline model of the
+compiled dry-run artifacts (TPU v5e target).
 
     compute term    = HLO_FLOPs_per_chip / peak_FLOP/s
     memory term     = HLO_bytes_per_chip / HBM_bw
@@ -16,17 +17,40 @@ yardstick; HLO_FLOPs/MODEL_FLOPS exposes remat/CG/attention overheads.
 from __future__ import annotations
 
 import dataclasses
+from typing import Optional
 
 from repro.configs.base import InputShape, ModelConfig
 
-# --- TPU v5e hardware constants (per chip) ---------------------------------
-PEAK_FLOPS_BF16 = 197e12  # FLOP/s
-HBM_BW = 819e9  # bytes/s
-ICI_LINK_BW = 50e9  # bytes/s per link (brief: ~50 GB/s/link)
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    flops_bf16: float  # FLOP/s
+    hbm_bw: float  # bytes/s
+    ici_link_bw: float  # bytes/s per chip-to-chip link
+
+
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``. A device
+# that is not here has no roofline: nothing is scaled by another chip's
+# peaks. Source: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+# 16 GB HBM at 819 GB/s, 1,600 Gbit/s interchip interconnect (4 links of
+# 50 GB/s).
+V5E = "TPU v5 lite"
+DEVICE_PEAKS = {
+    V5E: DevicePeaks(flops_bf16=197e12, hbm_bw=819e9, ici_link_bw=50e9),
+}
+
+
+def device_peaks(device_kind: str) -> Optional[DevicePeaks]:
+    """The peaks of ``device_kind``, or None for a device not in the table
+    (a CPU, say)."""
+    return DEVICE_PEAKS.get(device_kind)
 
 
 @dataclasses.dataclass(frozen=True)
 class Roofline:
+    """Roofline terms of one dry-run program on the production TPU v5e
+    mesh (a described target, so its peaks are the v5e's by definition)."""
+
     flops_per_chip: float
     bytes_per_chip: float
     collective_bytes_per_chip: float
@@ -35,15 +59,15 @@ class Roofline:
 
     @property
     def compute_s(self) -> float:
-        return self.flops_per_chip / PEAK_FLOPS_BF16
+        return self.flops_per_chip / DEVICE_PEAKS[V5E].flops_bf16
 
     @property
     def memory_s(self) -> float:
-        return self.bytes_per_chip / HBM_BW
+        return self.bytes_per_chip / DEVICE_PEAKS[V5E].hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes_per_chip / ICI_LINK_BW
+        return self.collective_bytes_per_chip / DEVICE_PEAKS[V5E].ici_link_bw
 
     @property
     def dominant(self) -> str:

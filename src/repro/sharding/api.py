@@ -1,5 +1,5 @@
-"""Logical-axis sharding constraints (MaxText-style, minimal) + shard_map
-version compatibility.
+"""Logical-axis sharding constraints (MaxText-style, minimal) + the one
+shard_map entry point.
 
 Model code calls ``constrain(x, ("batch", None, "embed"))`` with *logical*
 names. The launcher installs a rules table (logical name -> mesh axes) and a
@@ -8,12 +8,9 @@ model code runs on a laptop CPU and on a 512-chip mesh unchanged.
 
 ``shard_map_compat`` is the one place the repo enters a manual region: the
 federated engine (``repro.core.engine``) and the LM-scale federated step
-(``repro.core.fednew_hf``) both go through it, so the jax-version dance
-(``jax.shard_map`` with ``axis_names=`` on new jax vs
-``jax.experimental.shard_map.shard_map`` with ``auto=`` on jax<=0.4.x) lives
-here and nowhere else. Callers name the *manual* (client) axes; remaining
-mesh axes stay auto, per the client-axis convention in
-``repro.sharding.specs``.
+(``repro.core.fednew_hf``) both go through it. Callers name the *manual*
+(client) axes; remaining mesh axes stay auto, per the client-axis
+convention in ``repro.sharding.specs``.
 """
 
 from __future__ import annotations
@@ -64,34 +61,21 @@ def constrain(x, names):
     if rules is None or mesh is None:
         return x
     spec = logical_to_spec(names, rules)
-    # Inside a shard_map region (new-style jax) the tracing context carries an
-    # *abstract* mesh with some axes Manual; constraints must be expressed
-    # against it (our rules only ever name auto axes there — client axes are
-    # excluded). Older jax has no abstract-mesh API; the concrete mesh works.
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    am = get_am() if get_am is not None else None
-    if am is not None and not am.empty:
+    # Inside a shard_map region the tracing context carries an *abstract*
+    # mesh with some axes Manual; constraints must be expressed against it
+    # (our rules only ever name auto axes there — client axes are excluded).
+    am = jax.sharding.get_abstract_mesh()
+    if not am.empty:
         return jax.lax.with_sharding_constraint(x, NamedSharding(am, spec))
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
 def shard_map_compat(f, mesh, *, in_specs, out_specs, manual_axes):
-    """``shard_map`` across jax versions (see module docstring).
-
-    ``manual_axes`` are the mesh axes the body is manual over (the client
-    axes); every other mesh axis remains auto/GSPMD inside the region.
-    Replication checking is disabled on both paths — the federated bodies
-    establish replication through explicit pmeans."""
-    manual = frozenset(manual_axes)
-    if hasattr(jax, "shard_map"):  # jax >= 0.6-style API
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=set(manual), check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    auto = frozenset(mesh.axis_names) - manual
-    return _shard_map(
-        f, mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False, auto=auto,
+    """``jax.shard_map`` manual over ``manual_axes`` (the client axes);
+    every other mesh axis remains auto/GSPMD inside the region. Replication
+    checking is disabled — the federated bodies establish replication
+    through explicit pmeans."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        axis_names=set(manual_axes), check_vma=False,
     )

@@ -192,15 +192,14 @@ def _summarize_trace(payload: Dict[str, Any]) -> None:
     if roofline:
         print(f"  roofline: {len(roofline)} profiled dispatch(es)")
         for rec in roofline:
-            if "error" in rec:
-                print(f"    {rec['label']}: {rec['error']}")
-                continue
             frac = rec.get("achieved_fraction")
             ach = (f"{rec['achieved_flops_per_s']:.3e} FLOP/s "
                    f"({frac:.2e} of attainable)") if frac is not None \
-                else "unmeasured"
+                else "no roofline share"
+            bound = (f"{rec['bound']}-bound" if rec.get("bound")
+                     else f"no peaks for {rec.get('device_kind')!r}")
             print(f"    {rec['label']}: {rec['flops']:.3e} flops, "
-                  f"{rec['bytes']:.3e} bytes, {rec['bound']}-bound, {ach}")
+                  f"{rec['bytes']:.3e} bytes, {bound}, {ach}")
 
 
 def _summarize_stream(rows: List[Dict[str, Any]]) -> None:

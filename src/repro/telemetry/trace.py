@@ -213,7 +213,7 @@ class EngineTracer:
         self.wants_profile = profile
         #: per-dispatch (label, rounds, seconds) in call order
         self.dispatches: List[tuple] = []
-        #: label -> hlo_cost.analyze dict (or {"error": ...})
+        #: label -> hlo_cost.analyze dict
         self.costs: Dict[str, Dict[str, Any]] = {}
 
     @contextlib.contextmanager
@@ -234,8 +234,8 @@ class EngineTracer:
 
     def profile_dispatch(self, label: str, jitted, *args) -> None:
         """AOT-lower ``jitted(*args)``, analyze the optimized HLO, remember
-        the cost under ``label``. Failures are recorded, never raised — a
-        cost model must not be able to kill a run."""
+        the cost under ``label``. A lowering or compile error propagates:
+        the program that failed here is the one the run would dispatch."""
         if label in self.costs:
             return
         from repro.roofline import hlo_cost
@@ -246,11 +246,8 @@ class EngineTracer:
             else contextlib.nullcontext()
         )
         with cm:
-            try:
-                text = jitted.lower(*args).compile().as_text()
-                self.costs[label] = hlo_cost.analyze(text)
-            except Exception as e:  # pragma: no cover - backend-specific
-                self.costs[label] = {"error": f"{type(e).__name__}: {e}"}
+            text = jitted.lower(*args).compile().as_text()
+            self.costs[label] = hlo_cost.analyze(text)
 
     def roofline_records(self) -> List[Dict[str, Any]]:
         """Achieved-vs-attainable per profiled dispatch label, using the
@@ -263,9 +260,6 @@ class EngineTracer:
             by_label.setdefault(label, []).append((rounds, seconds))
         records = []
         for label, cost in self.costs.items():
-            if "error" in cost:
-                records.append({"label": label, **cost})
-                continue
             calls = by_label.get(label, [])
             seconds = min((s for _, s in calls), default=None)
             records.append(

@@ -171,18 +171,11 @@ def make_fednew_train_step(cfg: ModelConfig, mesh, shape: InputShape) -> StepBun
     # (XLA partial-manual grouping bug, see resolve_client_axes docstring);
     # other layouts (pod-federated big-client archs) take the vmap path with
     # the same explicit shardings — verified equivalent in
-    # tests/test_federated_equivalence.py. jax<=0.4.x XLA rejects ALL
-    # nontrivial partial-manual regions (CHECK sharding.IsManualSubgroup()),
-    # so there the vmap+GSPMD path is used whenever the remainder axes are
-    # real; fully-manual client meshes (engine path) are unaffected.
+    # tests/test_federated_equivalence.py.
     auto_rest = set(mesh.axis_names) - set(client_axes)
-    sizes = sh.mesh_axis_sizes(mesh)
-    partial_manual_ok = hasattr(jax, "shard_map") or all(
-        sizes[a] == 1 for a in auto_rest
-    )
     federated = (
         bool(client_axes) and n == n_axes and n > 1
-        and auto_rest == {"model"} and partial_manual_ok
+        and auto_rest == {"model"}
     )
     if n <= 1:
         client_axes = ()

@@ -478,3 +478,26 @@ def test_cli_template_round_trips():
     assert proc.returncode == 0, proc.stderr
     spec = api.ExperimentSpec.from_json(proc.stdout)
     assert spec.name == "template"
+
+
+@pytest.mark.parametrize("env_set", [False, True], ids=["default", "env"])
+def test_compile_cache_dir(tmp_path, env_set):
+    """The entry points' compile cache: where JAX_COMPILATION_CACHE_DIR is
+    set, JAX's own setting stands; otherwise the fixed <repo>/.jax_cache."""
+    repo = Path(__file__).resolve().parent.parent
+    env = {**os.environ, "PYTHONPATH": str(repo / "src")}
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    want = repo / ".jax_cache"
+    if env_set:
+        want = tmp_path / "cache"
+        env["JAX_COMPILATION_CACHE_DIR"] = str(want)
+    proc = subprocess.run(
+        [sys.executable, "-c",
+         "import jax\n"
+         "from repro.launch.compile_cache import enable_compile_cache\n"
+         "print(enable_compile_cache())\n"
+         "print(jax.config.jax_compilation_cache_dir)\n"],
+        capture_output=True, text=True, timeout=300, env=env,
+    )
+    assert proc.returncode == 0, proc.stderr
+    assert proc.stdout.split() == [str(want), str(want)]

@@ -154,7 +154,7 @@ def test_topk_residual_feedback_conserves_mass():
 
 def test_topk_value_bits_casts_wire_values():
     c = comm.build_codec({"name": "topk", "k": 2, "value_bits": 32})
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         y = jax.random.normal(jax.random.PRNGKey(0), (2, 8), jnp.float64)
         st = c.init_state(2, 8, jnp.float64)
         wire = c.encode(None, y, st, 0)

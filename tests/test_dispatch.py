@@ -72,15 +72,18 @@ def test_registry_serves_both_hot_loops():
 
 
 def test_registry_degrades_to_reference_on_import_error():
-    """The 'jnp reference as last resort' leg: an unimportable kernel falls
-    back to the registered reference, with the resolved flavor saying so."""
+    """An unimportable kernel raises: the reference never stands in for a
+    kernel the backend asked for (it would hide the device path), while the
+    reference flavor of the same entry still resolves."""
     dispatch.register_kernel(
         "broken_kernel",
         pallas="repro.kernels.nonexistent_module:fn",
         reference="repro.core.quantization:quantize_with_keys",
     )
     try:
-        fn, resolved = dispatch.resolve_impl("broken_kernel", backend="pallas")
+        with pytest.raises(ImportError, match="nonexistent_module"):
+            dispatch.resolve_impl("broken_kernel", backend="pallas")
+        fn, resolved = dispatch.resolve_impl("broken_kernel", backend="reference")
         assert fn is Q.quantize_with_keys
         assert resolved == "reference"
     finally:

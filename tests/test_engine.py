@@ -127,6 +127,27 @@ def test_block_plan_edge_cases():
     assert engine._block_plan(5, 0) == [5]
 
 
+@pytest.mark.parametrize("mode", ["scan", "host"])
+def test_dataset_is_an_argument_not_a_constant(problem, mode):
+    """The compiled rounds take the dataset as an argument: a closed-over
+    dataset is embedded in the program as a constant, which at a few GB of
+    client data exhausts host memory while compiling."""
+    import warnings
+
+    obj, data = problem
+    prev = jax.config.jax_captured_constants_warn_bytes
+    jax.config.update("jax_captured_constants_warn_bytes", 1 << 20)
+    try:
+        with warnings.catch_warnings():
+            warnings.filterwarnings(
+                "error", message=".*constants were captured.*"
+            )
+            engine.run(engine.get_solver("fednew", rho=RHO, alpha=ALPHA),
+                       obj, data, 2, key=KEY, mode=mode)
+    finally:
+        jax.config.update("jax_captured_constants_warn_bytes", prev)
+
+
 @pytest.mark.parametrize("rounds,block", [(1, None), (4, 1), (3, 64)],
                          ids=["rounds=1", "block=1", "block>rounds"])
 def test_run_edge_blocks_match_host(problem, rounds, block):

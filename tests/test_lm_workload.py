@@ -105,9 +105,7 @@ def test_autodiff_matches_closed_form_f32(logreg_pair):
 
 
 def test_autodiff_matches_closed_form_f64():
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         spec = synthetic.DatasetSpec(
             "custom", n_clients=4, samples_per_client=32, dim=D, sparse=False
         )

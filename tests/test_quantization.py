@@ -72,15 +72,13 @@ def test_payload_bits_exact_at_lm_scale():
     assert Q.payload_bits(8, d) == 8 * d + 32  # exact Python int, any scale
     assert Q.exact_payload_bits(d) == 32 * d
     # traced form: int64 (bit-exact) under x64 ...
-    from jax.experimental import disable_x64, enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         arr = Q.payload_bits_array(Q.payload_bits(8, d))
         assert arr.dtype == jnp.int64
         assert int(arr) == 8 * d + 32
     # ... and float32 (positive, 2^-24-relative) without — never negative.
     # (Explicitly disabled so the assertion holds under CI's x64 leg too.)
-    with disable_x64():
+    with jax.enable_x64(False):
         arr32 = Q.payload_bits_array(Q.payload_bits(8, d))
         assert arr32.dtype == jnp.float32
         assert float(arr32) > 0
@@ -99,12 +97,10 @@ def test_payload_bits_dtype_aware():
 
 def test_fedgd_payload_tracks_float64_state():
     """End-to-end satellite check: a float64 run reports 64·d uplink."""
-    from jax.experimental import enable_x64
-
     from repro.core import baselines
     from repro.core.objectives import ClientDataset, logistic_regression
 
-    with enable_x64():
+    with jax.enable_x64(True):
         key = jax.random.PRNGKey(0)
         feats = jax.random.normal(key, (4, 16, 10), jnp.float64)
         labels = jnp.sign(jax.random.normal(jax.random.fold_in(key, 1), (4, 16)))

@@ -384,13 +384,33 @@ def test_roofline_records(tmp_path):
     assert records
     rec = records[0]
     assert rec["label"].startswith("scan_block")
+    assert rec["device_kind"] == jax.devices()[0].device_kind
     assert rec["flops"] > 0
-    assert rec["attainable_flops_per_s"] > 0
-    assert rec["bound"] in ("compute", "memory")
+    # a CPU has no published peaks: no ceiling, no bound, no share
+    assert rec["attainable_flops_per_s"] is None
+    assert rec["bound"] is None
+    assert rec["achieved_fraction"] is None
     assert rec["seconds_per_call"] > 0
     assert rec["achieved_flops_per_s"] == pytest.approx(
         rec["flops"] / rec["seconds_per_call"]
     )
+
+
+def test_roofline_record_uses_the_device_kinds_peaks():
+    from repro.roofline.model import DEVICE_PEAKS, V5E
+
+    cost = {"flops": 4e9, "bytes": 1e9}
+    peaks = DEVICE_PEAKS[V5E]
+    rec = telemetry.roofline_record("k", cost, 0.01, device_kind=V5E)
+    assert rec["attainable_flops_per_s"] == pytest.approx(4 * peaks.hbm_bw)
+    assert rec["bound"] == "memory"
+    assert rec["achieved_fraction"] == pytest.approx(
+        (4e9 / 0.01) / (4 * peaks.hbm_bw)
+    )
+    other = telemetry.roofline_record("k", cost, 0.01, device_kind="cpu")
+    assert other["attainable_flops_per_s"] is None
+    assert other["achieved_fraction"] is None
+    assert other["achieved_flops_per_s"] == pytest.approx(4e11)
 
 
 def test_trace_file_loads_as_chrome_trace(tmp_path):
