@@ -235,7 +235,7 @@ def _finish_telemetry(spec: ExperimentSpec, rec, tracer) -> None:
     """Attach roofline records (when profiling) and write the trace file."""
     if rec is None:
         return
-    if tracer is not None and tracer.wants_profile:
+    if tracer is not None and tracer.profile:
         rec.other_data["roofline"] = tracer.roofline_records()
     rec.save(spec.telemetry.trace_path)
 

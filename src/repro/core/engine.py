@@ -34,6 +34,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import itertools
 import time
 from typing import Any, Callable, List, Optional, Tuple
 
@@ -302,13 +303,17 @@ def run(
                  synchronization at all.
     tracer=...   a duck-typed telemetry hook (``repro.telemetry.
                  EngineTracer``): ``span(name, **args)`` context managers
-                 wrap the host phases (init, each dispatch), and — when its
-                 ``wants_profile`` flag is set — ``profile_dispatch(label,
-                 jitted, *args)`` is offered each distinct compiled callable
-                 BEFORE it first executes (AOT lowering only; the
-                 computation never runs, so profiling cannot perturb the
-                 trajectory). ``None`` (default) is the historical
-                 zero-overhead path.
+                 wrap the host phases — ``init``; per distinct program
+                 ``trace``, ``lower`` and ``compile`` (a real compile or a
+                 persistent-cache load); per block ``dispatch`` (the call,
+                 blocked until ready) holding ``launch`` (the call
+                 returning) and ``wait`` — each with ``job=<n>``, a
+                 per-process run counter. An optional ``compiled(label,
+                 compiled)`` method receives each program once it is
+                 built, and — when its ``wants_profile`` flag is set —
+                 ``profile_dispatch(label, jitted, *args)`` is offered the
+                 carry before every block, in order. ``None`` (default)
+                 adds no synchronization and emits nothing.
     """
     if rounds <= 0:
         raise ValueError("rounds must be positive")
@@ -327,28 +332,12 @@ def run(
             timings=timings, tracer=tracer,
         )
 
-    with _span(tracer, "init", solver=solver.name):
-        state = solver.init(obj, data, key, x0)
-    # The dataset is an argument of every jitted call, never a closure: a
-    # closed-over array is embedded in the program as a constant, which at
-    # a few GB of client data exhausts host memory during compilation.
-    if part is None:
-        step1 = lambda s, d: solver.step(s, obj, d)
-        carry = state
-    else:
-        n = data.n_clients
-
-        def step1(c, d):
-            s, pkey = c
-            pkey, sub = participation_lib.split_round(pkey)
-            mask = participation_lib.round_mask(sub, n, part)
-            s, m = solver.step(s, obj, d, mask=mask)
-            return (s, pkey), m
-
-        carry = (state, part.init_key())
+    job = _Job(tracer, next(_JOB_IDS), timings)
+    with job.span("init", solver=solver.name):
+        carry = _init_carry(solver, obj, data, key, x0, part)
+    step1 = _round_fn(solver, obj, data.n_clients, part)
     if mode == "host":
-        carry, metrics = _host_loop(step1, carry, data, rounds, timings,
-                                    tracer)
+        carry, metrics = _host_loop(step1, carry, data, rounds, job)
     else:
         if donate:
             # init() may alias caller arrays (the PRNG key, x0); donating
@@ -356,45 +345,154 @@ def run(
             # caller.
             carry = jax.tree.map(jnp.copy, carry)
         carry, metrics = _scan_blocks(
-            step1, carry, data, rounds, block_size, donate, timings, tracer
+            step1, carry, data, rounds, block_size, donate, job
         )
     return (carry[0] if part is not None else carry), metrics
 
 
-def _span(tracer, name: str, **args):
-    """The tracer's host span, or a no-op when telemetry is off."""
-    if tracer is None:
-        return contextlib.nullcontext()
-    return tracer.span(name, **args)
+def _init_carry(solver, obj, data, key, x0, part):
+    """The round-0 carry: the solver's state, with the participation key
+    beside it when clients are sampled."""
+    state = solver.init(obj, data, key, x0)
+    return state if part is None else (state, part.init_key())
 
 
-def _profile(tracer, label: str, jitted, *args) -> None:
-    """Offer one compiled callable to the tracer's HLO cost capture (a
-    pre-execution AOT lowering; dedup'd by label inside the tracer)."""
-    if tracer is not None and getattr(tracer, "wants_profile", False):
-        tracer.profile_dispatch(label, jitted, *args)
+def _round_fn(solver, obj, n_clients: int, part):
+    """One round on the carry of :func:`_init_carry`. The dataset is an
+    argument of every jitted call, never a closure: a closed-over array is
+    embedded in the program as a constant, which at a few GB of client data
+    exhausts host memory during compilation."""
+    if part is None:
+        return lambda s, d: solver.step(s, obj, d)
+
+    def step1(c, d):
+        s, pkey = c
+        pkey, sub = participation_lib.split_round(pkey)
+        mask = participation_lib.round_mask(sub, n_clients, part)
+        s, m = solver.step(s, obj, d, mask=mask)
+        return (s, pkey), m
+
+    return step1
 
 
-def _timed(call, n_rounds: int, timings, tracer=None, label="step"):
-    """Run one dispatched jit call, optionally timing it to completion."""
-    if timings is None and tracer is None:
-        return call()
-    t0 = time.perf_counter()
-    with _span(tracer, "dispatch", label=label, rounds=n_rounds):
-        out = jax.block_until_ready(call())
-    if timings is not None:
-        timings.append((n_rounds, time.perf_counter() - t0))
-    return out
+def _block_jit(step1, donate: bool):
+    """The scan driver's block: ``length`` rounds (static) in one program,
+    the carry donated if ``donate``."""
+    def block(s, d, length):
+        return jax.lax.scan(lambda c, _: step1(c, d), s, None, length=length)
+
+    return jax.jit(
+        block, static_argnums=2, donate_argnums=(0,) if donate else ()
+    )
 
 
-def _host_loop(step1, state, data, rounds: int, timings=None, tracer=None):
+def compile_block(
+    solver: FederatedSolver,
+    obj: Objective,
+    data: ClientDataset,
+    length: int,
+    *,
+    key: Optional[jax.Array] = None,
+    x0=None,
+    participation: Optional[participation_lib.Participation] = None,
+):
+    """The program ``run(mode="scan")`` dispatches (carry donated, the
+    default) for a block of ``length`` rounds on one device, built from the
+    shapes of ``data`` and ``key`` (arrays or ``jax.ShapeDtypeStruct``)
+    without running a round: for reading its optimized HLO
+    (``.as_text()``) or memory footprint."""
+    key = jax.random.PRNGKey(0) if key is None else key
+    part = participation if (participation and participation.active) else None
+    carry = jax.eval_shape(
+        lambda d, k: _init_carry(solver, obj, d, k, x0, part), data, key
+    )
+    step1 = _round_fn(solver, obj, data.n_clients, part)
+    return _block_jit(step1, donate=True).trace(carry, data, length).lower().compile()
+
+
+def _arg_type(x):
+    """What ``jax.jit`` keys a compiled program on for one argument: its
+    abstract value and sharding (``.aval`` is a tenth of ``jax.typeof``'s
+    cost on an array, which matters in host mode, once a round)."""
+    aval = getattr(x, "aval", None)
+    return (jax.typeof(x) if aval is None else aval,
+            getattr(x, "sharding", None))
+
+
+# Per-process run counter: every span of one ``run`` carries its ``job``.
+_JOB_IDS = itertools.count()
+
+
+@dataclasses.dataclass
+class _Job:
+    """One ``run``'s telemetry: its tracer, its id and the caller's
+    ``timings`` list. Builds each distinct program once, with JAX's staged
+    API, and dispatches the ``Compiled`` object."""
+
+    tracer: Any
+    id: int
+    timings: Optional[List[Tuple[int, float]]]
+    programs: dict = dataclasses.field(default_factory=dict)
+
+    def span(self, name: str, **args):
+        """The tracer's host span, or a no-op when telemetry is off."""
+        if self.tracer is None:
+            return contextlib.nullcontext()
+        return self.tracer.span(name, job=self.id, **args)
+
+    def profile(self, label: str, jitted, *args) -> None:
+        """Offer the carry (and the program's other arguments) to the
+        tracer before a block runs."""
+        if getattr(self.tracer, "wants_profile", False):
+            self.tracer.profile_dispatch(label, jitted, *args)
+
+    def program(self, label: str, jitted, *args, static=()):
+        """``jitted`` built for these arguments: traced, lowered and
+        compiled on first use (one span each), then reused for every call
+        with the same argument types and shardings, as ``jax.jit`` would.
+        (The sharded driver's carry can change sharding after its first
+        block, so the label alone does not name the program.)"""
+        leaves, tree = jax.tree.flatten(args)
+        sig = (label, tree, tuple(map(_arg_type, leaves)))
+        exe = self.programs.get(sig)
+        if exe is None:
+            with self.span("trace", label=label):
+                traced = jitted.trace(*args, *static)
+            with self.span("lower", label=label):
+                lowered = traced.lower()
+            with self.span("compile", label=label):
+                exe = lowered.compile()
+            hook = getattr(self.tracer, "compiled", None)
+            if hook is not None:
+                hook(label, exe)
+            self.programs[sig] = exe
+        return exe
+
+    def dispatch(self, label: str, jitted, args, n_rounds: int, static=()):
+        """Build (first time) and run one program call. With a tracer or
+        ``timings`` the call is blocked until ready and timed from before
+        the build, so a run's first entry includes trace and compile."""
+        if self.tracer is None and self.timings is None:
+            return self.program(label, jitted, *args, static=static)(*args)
+        t0 = time.perf_counter()
+        exe = self.program(label, jitted, *args, static=static)
+        with self.span("dispatch", label=label, rounds=n_rounds):
+            with self.span("launch", label=label):
+                out = exe(*args)
+            with self.span("wait", label=label):
+                out = jax.block_until_ready(out)
+        if self.timings is not None:
+            self.timings.append((n_rounds, time.perf_counter() - t0))
+        return out
+
+
+def _host_loop(step1, state, data, rounds: int, job: _Job):
     """The historical driver: jit one step, iterate on the host."""
     jstep = jax.jit(step1)
-    _profile(tracer, "host_step", jstep, state, data)
+    job.profile("host_step", jstep, state, data)
     history = []
     for _ in range(rounds):
-        state, m = _timed(lambda: jstep(state, data), 1, timings, tracer,
-                          "host_step")
+        state, m = job.dispatch("host_step", jstep, (state, data), 1)
         history.append(m)
     return state, jax.tree.map(lambda *xs: jnp.stack(xs), *history)
 
@@ -414,19 +512,13 @@ def _concat_metrics(chunks):
 
 
 def _scan_blocks(step1, state, data, rounds: int, block_size, donate: bool,
-                 timings=None, tracer=None):
-    def block(s, d, length):
-        return jax.lax.scan(lambda c, _: step1(c, d), s, None, length=length)
-
-    jblock = jax.jit(
-        block, static_argnums=2, donate_argnums=(0,) if donate else ()
-    )
+                 job: _Job):
+    jblock = _block_jit(step1, donate)
     chunks = []
     for n in _block_plan(rounds, block_size):
         label = f"scan_block[{n}r]"
-        _profile(tracer, label, jblock, state, data, n)
-        state, m = _timed(lambda: jblock(state, data, n), n, timings, tracer,
-                          label)
+        job.profile(label, jblock, state, data, n)
+        state, m = job.dispatch(label, jblock, (state, data), n, static=(n,))
         chunks.append(m)
     return state, _concat_metrics(chunks)
 
@@ -465,7 +557,8 @@ def _run_sharded(
 
     # Round-0 state is built on the full dataset on the default device, then
     # laid out: per-client rows split over the client axis, rest replicated.
-    with _span(tracer, "init", solver=solver.name):
+    job = _Job(tracer, next(_JOB_IDS), timings)
+    with job.span("init", solver=solver.name):
         state = solver.init(obj, data, key, x0)
     if donate:
         state = jax.tree.map(jnp.copy, state)  # don't donate caller aliases
@@ -515,8 +608,8 @@ def _run_sharded(
     for length in _block_plan(rounds, block_size):
         jfn = jitted(length)
         label = f"shard_block[{length}r]"
-        _profile(tracer, label, jfn, carry, data)
-        carry, m = _timed(lambda: jfn(carry, data), length, timings, tracer, label)
+        job.profile(label, jfn, carry, data)
+        carry, m = job.dispatch(label, jfn, (carry, data), length)
         chunks.append(m)
     final = carry[0] if part is not None else carry
     return final, _concat_metrics(chunks)

@@ -52,6 +52,13 @@ step encodes the per-client directions, aggregates the *decoded* (PS-side)
 reconstructions in eq. 13, and carries the codec's per-client state in
 ``FedNewState.comm``.
 
+Each layer of a round runs under a ``jax.named_scope``, which reaches the
+``op_name`` of its ops in the optimized HLO and changes nothing else:
+``fednew.hessian`` (the curvature refresh), ``fednew.grad`` (local
+gradients, the eq. 9 right side), ``fednew.eq9`` (the client solve),
+``fednew.codec`` (uplink encode, decode, codec state), ``fednew.aggregate``
+(eqs. 13, 12, 14 and the bit metric) and ``fednew.eval`` (StepMetrics).
+
 Communication accounting follows the paper: the metric of record is uplink
 bits per client per round — w·d for FedNew (w = word bits of the transmitted
 dtype, 32 for float32), ``bits``·d + 32 for Q-FedNew, the codec's exact
@@ -465,77 +472,83 @@ def _step_tree(
     n_local = jax.tree.leaves(state.lam)[0].shape[0]
     # -- local Hessian refresh: re-anchor sampled clients' curvature at x^k --
     if cfg.hessian_period > 0:
-        refresh = (state.step % cfg.hessian_period) == 0
-        curv = jax.lax.cond(
-            refresh,
-            lambda: admm.bcast_clients(state.x, n_local),
-            lambda: state.curv,
-        )
-        if mask is not None:
-            curv = admm.mask_client_rows(mask, curv, state.curv)
+        with jax.named_scope("fednew.hessian"):
+            refresh = (state.step % cfg.hessian_period) == 0
+            curv = jax.lax.cond(
+                refresh,
+                lambda: admm.bcast_clients(state.x, n_local),
+                lambda: state.curv,
+            )
+            if mask is not None:
+                curv = admm.mask_client_rows(mask, curv, state.curv)
     else:
         curv = state.curv
 
-    g_i = obj.local_grad(state.x, data)  # per-leaf (n, ...) — never transmitted
+    with jax.named_scope("fednew.grad"):
+        g_i = obj.local_grad(state.x, data)  # per-leaf (n, ...), never sent
+        rhs = admm.admm_rhs(
+            g_i, state.lam, admm.bcast_clients(state.y, n_local), cfg.rho
+        )
 
     # -- eq. 9: batched damped CG on the autodiff HVP oracle ----------------
-    rhs = admm.admm_rhs(
-        g_i, state.lam, admm.bcast_clients(state.y, n_local), cfg.rho
-    )
-    cg_res = hvp.cg_solve_clients(
-        lambda v: obj.local_hvp(curv, data, v),
-        rhs,
-        damping=cfg.damping,
-        iters=cfg.cg_iters,
-        tol=cfg.cg_tol,
-        track_iters=cfg.diagnostics,
-    )
-    y_i = cg_res.x
+    with jax.named_scope("fednew.eq9"):
+        cg_res = hvp.cg_solve_clients(
+            lambda v: obj.local_hvp(curv, data, v),
+            rhs,
+            damping=cfg.damping,
+            iters=cfg.cg_iters,
+            tol=cfg.cg_tol,
+            track_iters=cfg.diagnostics,
+        )
+        y_i = cg_res.x
 
     # -- uplink compression: the codec applied leaf-wise --------------------
     codec = cfg.build_codec()
-    if codec.needs_rng:
-        key, sub = jax.random.split(state.key)
-    else:
-        key, sub = state.key, state.key  # sub unused by deterministic codecs
-    y_i_tx, comm_state = comm.encode_decode_tree(
-        codec, sub, y_i, state.comm, step=state.step
-    )
-    if mask is not None:
-        comm_state = admm.mask_client_rows(mask, comm_state, state.comm)
+    with jax.named_scope("fednew.codec"):
+        if codec.needs_rng:
+            key, sub = jax.random.split(state.key)
+        else:
+            key, sub = state.key, state.key  # deterministic codecs: unused
+        y_i_tx, comm_state = comm.encode_decode_tree(
+            codec, sub, y_i, state.comm, step=state.step
+        )
+        if mask is not None:
+            comm_state = admm.mask_client_rows(mask, comm_state, state.comm)
 
-    # -- eqs. 13 + 12: the ONLY communication + dual update -----------------
-    y = admm.tree_mean_clients(y_i_tx, None, weights=mask)
-    lam = admm.dual_update(
-        state.lam, y_i_tx, admm.bcast_clients(y, n_local), cfg.rho,
-        weights=mask,
-    )
+    with jax.named_scope("fednew.aggregate"):
+        # -- eqs. 13 + 12: the ONLY communication + dual update -------------
+        y = admm.tree_mean_clients(y_i_tx, None, weights=mask)
+        lam = admm.dual_update(
+            state.lam, y_i_tx, admm.bcast_clients(y, n_local), cfg.rho,
+            weights=mask,
+        )
 
-    # -- exact per-leaf uplink accounting -----------------------------------
-    bits = comm.tree_payload_bits_metric(codec, y, state.step)
-    if mask is not None:
-        from repro.core import participation
+        # -- exact per-leaf uplink accounting -------------------------------
+        bits = comm.tree_payload_bits_metric(codec, y, state.step)
+        if mask is not None:
+            from repro.core import participation
 
-        bits = participation.masked_bits_metric(bits, mask, None)
+            bits = participation.masked_bits_metric(bits, mask, None)
 
-    x = jax.tree.map(lambda p, yl: p - yl, state.x, y)  # eq. 14
+        x = jax.tree.map(lambda p, yl: p - yl, state.x, y)  # eq. 14
 
     new_state = FedNewState(
         x=x, y=y, lam=lam, curv=curv, comm=comm_state, key=key,
         step=state.step + 1,
     )
-    metrics = StepMetrics(
-        loss=obj.global_loss(x, data),
-        grad_norm=hvp.tree_norm(obj.global_grad(x, data)),
-        uplink_bits_per_client=bits,
-        dual_sum_residual=admm.dual_sum_residual(lam),
-        direction_norm=hvp.tree_norm(y),
-    )
-    if cfg.diagnostics:
-        metrics = _diag_metrics(
-            state, cfg, metrics, y_i=y_i, y_i_tx=y_i_tx, y=y, curv=curv,
-            cg_info=cg_res, mask=mask, axis_name=None,
+    with jax.named_scope("fednew.eval"):
+        metrics = StepMetrics(
+            loss=obj.global_loss(x, data),
+            grad_norm=hvp.tree_norm(obj.global_grad(x, data)),
+            uplink_bits_per_client=bits,
+            dual_sum_residual=admm.dual_sum_residual(lam),
+            direction_norm=hvp.tree_norm(y),
         )
+        if cfg.diagnostics:
+            metrics = _diag_metrics(
+                state, cfg, metrics, y_i=y_i, y_i_tx=y_i_tx, y=y, curv=curv,
+                cg_info=cg_res, mask=mask, axis_name=None,
+            )
     return new_state, metrics
 
 
@@ -586,28 +599,32 @@ def step(
     n_local = state.lam.shape[0]
     # -- local Hessian refresh (pure client-side compute; no communication) --
     if cfg.hessian_period > 0:
-        refresh = (state.step % cfg.hessian_period) == 0
-        curv = jax.lax.cond(
-            refresh,
-            lambda: _fresh_curv(obj, state.x, data, cfg, n_local),
-            lambda: state.curv,
-        )
-        if mask is not None:
-            # Only sampled clients saw x^k; the rest keep the stale factor.
-            curv = _mask_rows(mask, curv, state.curv)
+        with jax.named_scope("fednew.hessian"):
+            refresh = (state.step % cfg.hessian_period) == 0
+            curv = jax.lax.cond(
+                refresh,
+                lambda: _fresh_curv(obj, state.x, data, cfg, n_local),
+                lambda: state.curv,
+            )
+            if mask is not None:
+                # Only sampled clients saw x^k; the rest keep the stale factor.
+                curv = _mask_rows(mask, curv, state.curv)
     else:
         curv = state.curv
 
-    g_i = obj.local_grad(state.x, data)  # (n, d) — never transmitted
+    with jax.named_scope("fednew.grad"):
+        g_i = obj.local_grad(state.x, data)  # (n, d) — never transmitted
+        rhs = admm.admm_rhs(
+            g_i, state.lam, jnp.broadcast_to(state.y, g_i.shape), cfg.rho
+        )
 
     # -- eq. 9: client sub-problem solve ------------------------------------
-    rhs = admm.admm_rhs(
-        g_i, state.lam, jnp.broadcast_to(state.y, g_i.shape), cfg.rho
-    )
-    if cfg.diagnostics:
-        y_i, cg_info = _local_solve(curv, rhs, cfg, obj, data, with_info=True)
-    else:
-        y_i = _local_solve(curv, rhs, cfg, obj, data)
+    with jax.named_scope("fednew.eq9"):
+        if cfg.diagnostics:
+            y_i, cg_info = _local_solve(curv, rhs, cfg, obj, data,
+                                        with_info=True)
+        else:
+            y_i = _local_solve(curv, rhs, cfg, obj, data)
 
     # -- uplink compression (repro.comm codec) ------------------------------
     # Encode client-side, aggregate the PS-side decode: eq. 13 and the dual
@@ -615,54 +632,57 @@ def step(
     # (every client knows its own reconstruction). Deterministic codecs never
     # touch the PRNG — plain FedNew's key stays bit-frozen, as it always was.
     codec = cfg.build_codec()
-    if codec.needs_rng:
-        key, sub = jax.random.split(state.key)
-        keys = _client_keys(sub, y_i.shape[0], axis_name, n_global_clients)
-    else:
-        key, keys = state.key, None
-    wire = codec.encode(keys, y_i, state.comm, state.step)
-    y_i_tx = codec.decode(wire, state.comm, state.step)
-    comm_state = codec.update_state(y_i_tx, y_i, state.comm, state.step)
-    if mask is not None:
-        # Sampled clients advance their codec state (ŷ / EF residual); the
-        # rest encoded nothing this round and keep it stale. Their y_i_tx
-        # rows are irrelevant: the weighted aggregates zero them out.
-        comm_state = _mask_rows(mask, comm_state, state.comm)
+    with jax.named_scope("fednew.codec"):
+        if codec.needs_rng:
+            key, sub = jax.random.split(state.key)
+            keys = _client_keys(sub, y_i.shape[0], axis_name, n_global_clients)
+        else:
+            key, keys = state.key, None
+        wire = codec.encode(keys, y_i, state.comm, state.step)
+        y_i_tx = codec.decode(wire, state.comm, state.step)
+        comm_state = codec.update_state(y_i_tx, y_i, state.comm, state.step)
+        if mask is not None:
+            # Sampled clients advance their codec state (ŷ / EF residual); the
+            # rest encoded nothing this round and keep it stale. Their y_i_tx
+            # rows are irrelevant: the weighted aggregates zero them out.
+            comm_state = _mask_rows(mask, comm_state, state.comm)
 
-    # -- eqs. 13 + 12: the ONLY communication + dual update -----------------
-    y = admm.tree_mean_clients(y_i_tx, axis_name, weights=mask)
-    lam = admm.dual_update(
-        state.lam, y_i_tx, jnp.broadcast_to(y, y_i_tx.shape), cfg.rho,
-        weights=mask,
-    )
+    with jax.named_scope("fednew.aggregate"):
+        # -- eqs. 13 + 12: the ONLY communication + dual update -------------
+        y = admm.tree_mean_clients(y_i_tx, axis_name, weights=mask)
+        lam = admm.dual_update(
+            state.lam, y_i_tx, jnp.broadcast_to(y, y_i_tx.shape), cfg.rho,
+            weights=mask,
+        )
 
-    # -- exact uplink accounting --------------------------------------------
-    bits = codec.payload_bits_metric(
-        data.dim, word_bits(y_i_tx), state.step
-    )
-    if mask is not None:
-        from repro.core import participation
+        # -- exact uplink accounting ----------------------------------------
+        bits = codec.payload_bits_metric(
+            data.dim, word_bits(y_i_tx), state.step
+        )
+        if mask is not None:
+            from repro.core import participation
 
-        bits = participation.masked_bits_metric(bits, mask, axis_name)
+            bits = participation.masked_bits_metric(bits, mask, axis_name)
 
-    x = state.x - y  # outer Newton step (eq. 14)
+        x = state.x - y  # outer Newton step (eq. 14)
 
     new_state = FedNewState(
         x=x, y=y, lam=lam, curv=curv, comm=comm_state, key=key,
         step=state.step + 1,
     )
-    metrics = StepMetrics(
-        loss=obj.global_loss(x, data),
-        grad_norm=jnp.linalg.norm(obj.global_grad(x, data)),
-        uplink_bits_per_client=bits,
-        dual_sum_residual=admm.dual_sum_residual(lam, axis_name),
-        direction_norm=jnp.linalg.norm(y),
-    )
-    if cfg.diagnostics:
-        metrics = _diag_metrics(
-            state, cfg, metrics, y_i=y_i, y_i_tx=y_i_tx, y=y, curv=curv,
-            cg_info=cg_info, mask=mask, axis_name=axis_name,
+    with jax.named_scope("fednew.eval"):
+        metrics = StepMetrics(
+            loss=obj.global_loss(x, data),
+            grad_norm=jnp.linalg.norm(obj.global_grad(x, data)),
+            uplink_bits_per_client=bits,
+            dual_sum_residual=admm.dual_sum_residual(lam, axis_name),
+            direction_norm=jnp.linalg.norm(y),
         )
+        if cfg.diagnostics:
+            metrics = _diag_metrics(
+                state, cfg, metrics, y_i=y_i, y_i_tx=y_i_tx, y=y, curv=curv,
+                cg_info=cg_info, mask=mask, axis_name=axis_name,
+            )
     return new_state, metrics
 
 

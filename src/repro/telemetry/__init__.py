@@ -5,8 +5,7 @@ The observability layer of the runtime (docs/telemetry.md):
   * :mod:`repro.telemetry.trace` — :class:`TraceRecorder` (Chrome-trace
     JSON, host + simulated clock domains) and :class:`EngineTracer` (the
     duck-typed hook ``engine.run`` / ``events.run_events`` accept).
-  * :mod:`repro.telemetry.metrics` — typed counters (exact ints), gauges,
-    histograms, and the JSONL diagnostics stream.
+  * :mod:`repro.telemetry.metrics` — the JSONL diagnostics stream.
   * :mod:`repro.telemetry.diagnostics` — the ``diag_`` metric-field
     convention, the runner-side split, and the solver-agnostic
     :func:`instrument` wrapper.
@@ -27,14 +26,7 @@ from repro.telemetry.diagnostics import (
     instrument,
     split_metric_lists,
 )
-from repro.telemetry.metrics import (
-    Counter,
-    Gauge,
-    Histogram,
-    MetricsRegistry,
-    read_stream,
-    stream_rows,
-)
+from repro.telemetry.metrics import read_stream, stream_rows
 from repro.telemetry.profile import analyze_jitted, roofline_record
 from repro.telemetry.trace import (
     HOST_PID,
@@ -47,11 +39,7 @@ __all__ = [
     "DIAG_PREFIX",
     "HOST_PID",
     "SIM_PID",
-    "Counter",
     "EngineTracer",
-    "Gauge",
-    "Histogram",
-    "MetricsRegistry",
     "TraceRecorder",
     "analyze_jitted",
     "generic_extras",

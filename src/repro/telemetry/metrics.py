@@ -1,14 +1,8 @@
-"""Typed metrics: counters (exact ints), gauges, histograms, JSONL streams.
+"""The JSONL diagnostics stream: one JSON object per round.
 
-The registry mirrors the repo's accounting discipline: anywhere the exact
-bit ledger is the source of truth, the telemetry counter is a Python int
-(arbitrary precision, never rounded through a float — the PR-2 contract);
-measured quantities go through gauges/histograms as floats. ``as_dict`` is
-JSON-able as-is and keeps the int/float split intact.
-
-The JSONL stream (:func:`stream_rows`) is the per-round escape hatch: one
-JSON object per line, so multi-million-round runs can be tailed without
-parsing one giant RunResult.
+:func:`stream_rows` is the per-round escape hatch: one JSON object per
+line, so multi-million-round runs can be tailed without parsing one giant
+RunResult. Exact ints (the bit ledgers) stay Python ints on the way out.
 """
 
 from __future__ import annotations
@@ -16,114 +10,6 @@ from __future__ import annotations
 import json
 import os
 from typing import Any, Dict, Iterable, List, Mapping
-
-
-class Counter:
-    """Monotone exact-integer counter (ledger-grade: Python ints only)."""
-
-    def __init__(self, name: str) -> None:
-        self.name = name
-        self.value: int = 0
-
-    def inc(self, amount: int = 1) -> None:
-        if not isinstance(amount, int) or isinstance(amount, bool):
-            raise TypeError(
-                f"counter {self.name!r} takes exact Python ints, got "
-                f"{type(amount).__name__} (ledger-grade counts never round "
-                f"through floats)"
-            )
-        if amount < 0:
-            raise ValueError(
-                f"counter {self.name!r} is monotone; got increment {amount}"
-            )
-        self.value += amount
-
-
-class Gauge:
-    """Last-write-wins float value."""
-
-    def __init__(self, name: str) -> None:
-        self.name = name
-        self.value: float = 0.0
-
-    def set(self, value: float) -> None:
-        self.value = float(value)
-
-
-class Histogram:
-    """Value distribution with a deterministic summary (count / min / max /
-    mean / p50 / p90). Keeps the raw observations — the runs this repo
-    records are bounded by rounds, not by request volume."""
-
-    def __init__(self, name: str) -> None:
-        self.name = name
-        self.values: List[float] = []
-
-    def observe(self, value: float) -> None:
-        self.values.append(float(value))
-
-    def observe_many(self, values: Iterable[float]) -> None:
-        for v in values:
-            self.observe(v)
-
-    @staticmethod
-    def _quantile(ordered: List[float], q: float) -> float:
-        # nearest-rank on the sorted list: deterministic, no interpolation
-        idx = min(len(ordered) - 1, int(q * len(ordered)))
-        return ordered[idx]
-
-    def summary(self) -> Dict[str, Any]:
-        if not self.values:
-            return {"count": 0}
-        ordered = sorted(self.values)
-        return {
-            "count": len(ordered),
-            "min": ordered[0],
-            "max": ordered[-1],
-            "mean": sum(ordered) / len(ordered),
-            "p50": self._quantile(ordered, 0.50),
-            "p90": self._quantile(ordered, 0.90),
-        }
-
-
-class MetricsRegistry:
-    """Name-keyed counters/gauges/histograms; re-requesting a name returns
-    the same instrument, requesting it as a different type is an error."""
-
-    def __init__(self) -> None:
-        self._instruments: Dict[str, Any] = {}
-
-    def _get(self, name: str, cls):
-        inst = self._instruments.get(name)
-        if inst is None:
-            inst = self._instruments[name] = cls(name)
-        elif not isinstance(inst, cls):
-            raise TypeError(
-                f"metric {name!r} already registered as "
-                f"{type(inst).__name__}, requested as {cls.__name__}"
-            )
-        return inst
-
-    def counter(self, name: str) -> Counter:
-        return self._get(name, Counter)
-
-    def gauge(self, name: str) -> Gauge:
-        return self._get(name, Gauge)
-
-    def histogram(self, name: str) -> Histogram:
-        return self._get(name, Histogram)
-
-    def as_dict(self) -> Dict[str, Any]:
-        out: Dict[str, Any] = {}
-        for name in sorted(self._instruments):
-            inst = self._instruments[name]
-            if isinstance(inst, Counter):
-                out[name] = inst.value  # exact int, by construction
-            elif isinstance(inst, Gauge):
-                out[name] = inst.value
-            else:
-                out[name] = inst.summary()
-        return out
 
 
 def stream_rows(path: str, rows: Iterable[Mapping[str, Any]]) -> str:

@@ -6,12 +6,13 @@ Events live in one of two *clock domains*, rendered as two separate
 processes in the viewer:
 
   * **host** (``pid == HOST_PID``) — wall-clock spans around the phases the
-    engine actually executes on this machine: ``init``, each ``dispatch``
-    (jit call), ``eval``, ``flush``, ``hlo-analyze``. Timestamps are
-    ``time.perf_counter`` deltas from recorder creation. Host spans are
-    *observations*; they never feed back into a trajectory (the fedlint
-    ``nondeterminism`` rule exempts exactly this package — and nothing
-    else — from its wall-clock ban; see docs/analysis.md).
+    engine actually executes on this machine: ``init``, per program
+    ``trace`` / ``lower`` / ``compile``, each ``dispatch`` (one program
+    call, holding ``launch`` and ``wait``), ``eval``, ``hlo-analyze``.
+    Timestamps are ``time.perf_counter`` deltas from recorder creation.
+    Host spans are *observations*; they never feed back into a trajectory
+    (the fedlint ``nondeterminism`` rule exempts exactly this package —
+    and nothing else — from its wall-clock ban; see docs/analysis.md).
 
   * **simulated** (``pid == SIM_PID``) — spans on the *simulated* timeline
     of the event heap / netsim: per-client download / compute / upload
@@ -197,34 +198,41 @@ class TraceRecorder:
 
 class EngineTracer:
     """What ``engine.run(tracer=...)`` / ``run_events(tracer=...)`` accept:
-    host spans plus optional per-dispatch HLO cost capture.
+    host spans plus optional per-program HLO cost capture.
 
     The engine stays ignorant of this module (duck-typed hook) — it calls
-    ``span(name, **args)`` around each phase and, when :attr:`wants_profile`
-    is set, ``profile_dispatch(label, jitted, *args)`` once per distinct
-    compiled callable BEFORE executing it (the AOT lowering never runs the
-    computation, so profiling cannot perturb a trajectory).
+    ``span(name, **args)`` around each phase (``init``; ``trace``,
+    ``lower``, ``compile`` per program; ``dispatch`` holding ``launch`` and
+    ``wait`` per block) and ``compiled(label, compiled)`` once per program
+    it builds. Each span is also a ``jax.profiler.TraceAnnotation`` named
+    ``engine.<name>``, so a profiler trace of the run shows the phases on
+    the device trace's clock. With ``profile=True`` the optimized HLO of
+    each program the run dispatches is analysed: the program itself, never
+    a second lowering.
     """
 
     def __init__(
         self, recorder: Optional[TraceRecorder] = None, profile: bool = False
     ) -> None:
         self.recorder = recorder
-        self.wants_profile = profile
+        self.profile = profile
         #: per-dispatch (label, rounds, seconds) in call order
         self.dispatches: List[tuple] = []
         #: label -> hlo_cost.analyze dict
         self.costs: Dict[str, Dict[str, Any]] = {}
 
+    def _host_span(self, name: str, **args):
+        if self.recorder is None:
+            return contextlib.nullcontext()
+        return self.recorder.host_span(name, cat="engine", **args)
+
     @contextlib.contextmanager
     def span(self, name: str, **args):
+        import jax
+
         t0 = time.perf_counter()
-        cm = (
-            self.recorder.host_span(name, cat="engine", **args)
-            if self.recorder is not None
-            else contextlib.nullcontext()
-        )
-        with cm:
+        with self._host_span(name, **args), \
+                jax.profiler.TraceAnnotation(f"engine.{name}"):
             yield
         if name == "dispatch":
             self.dispatches.append(
@@ -232,22 +240,15 @@ class EngineTracer:
                  time.perf_counter() - t0)
             )
 
-    def profile_dispatch(self, label: str, jitted, *args) -> None:
-        """AOT-lower ``jitted(*args)``, analyze the optimized HLO, remember
-        the cost under ``label``. A lowering or compile error propagates:
-        the program that failed here is the one the run would dispatch."""
-        if label in self.costs:
+    def compiled(self, label: str, compiled) -> None:
+        """Analyse one built program's optimized HLO and remember the cost
+        under ``label`` (the first program of a label is kept)."""
+        if not self.profile or label in self.costs:
             return
         from repro.roofline import hlo_cost
 
-        cm = (
-            self.recorder.host_span("hlo-analyze", cat="engine", label=label)
-            if self.recorder is not None
-            else contextlib.nullcontext()
-        )
-        with cm:
-            text = jitted.lower(*args).compile().as_text()
-            self.costs[label] = hlo_cost.analyze(text)
+        with self._host_span("hlo-analyze", label=label):
+            self.costs[label] = hlo_cost.analyze(compiled.as_text())
 
     def roofline_records(self) -> List[Dict[str, Any]]:
         """Achieved-vs-attainable per profiled dispatch label, using the
