@@ -91,10 +91,14 @@ def _spd(key, n, d, cond=50.0):
     return jnp.einsum("nij,nj,nkj->nik", Q, jnp.broadcast_to(eigs, (n, d)), Q)
 
 
-@pytest.mark.parametrize("d", [40, 99, 128, 263])
+@pytest.mark.parametrize(
+    "n,d",
+    [(4, 40), (4, 99), (4, 128), (4, 263), (1, 40), (7, 99), (15, 267), (4, 267)],
+)
 @pytest.mark.parametrize("damping", [0.5, 2.0])
-def test_client_solve_matches_direct(d, damping):
-    n = 4
+def test_client_solve_matches_direct(n, d, damping):
+    """n = 15 leaves a ragged last block of 7 clients; n = 1 and 7 make a
+    block of the whole batch."""
     kA, kb = jax.random.split(jax.random.PRNGKey(d))
     A = _spd(kA, n, d)
     b = jax.random.normal(kb, (n, d), jnp.float32)
@@ -104,14 +108,38 @@ def test_client_solve_matches_direct(d, damping):
 
 
 def test_client_solve_padding_exact_zero():
-    """Padded coordinates must solve to 0 and not perturb the true block."""
-    n, d = 2, 70  # pads to 128
+    """An unaligned d (70) solves correctly with no pad: the Hessians reach
+    the kernel as they are, with no pad or scatter in the wrapper."""
+    n, d = 2, 70
     kA, kb = jax.random.split(jax.random.PRNGKey(7))
     A = _spd(kA, n, d, cond=10.0)
     b = jax.random.normal(kb, (n, d), jnp.float32)
-    got = cs_ops.client_solve(A, b, damping=1.0, iters=96, interpret=True)
+    solve = lambda A, b: cs_ops.client_solve(A, b, damping=1.0, iters=96, interpret=True)
+    program = str(jax.make_jaxpr(solve)(A, b))
+    assert "pallas_call[" in program
+    assert "pad[" not in program and "scatter[" not in program
+    got = solve(A, b)
     ref = client_solve_ref(A, b, damping=1.0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-4, rtol=2e-3)
+
+
+def test_clients_per_step_fits_vmem_budget():
+    """8 clients a step at w8a's d, 1 at MAX_DIM, never more than n, and
+    the chosen double-buffered A tiles within the budget MAX_DIM sets."""
+    assert cs_ops.clients_per_step(60, 267) == 8
+    assert cs_ops.clients_per_step(15, 267) == 8
+    assert cs_ops.clients_per_step(3, 267) == 3
+    assert cs_ops.clients_per_step(2, cs_ops.MAX_DIM) == 1
+
+    def tile_bytes(d):  # one client's f32 (d, d) tile as VMEM lays it out
+        return -(-d // 8) * 8 * (-(-d // 128) * 128) * 4
+
+    budget = 2 * tile_bytes(cs_ops.MAX_DIM)
+    for d in (40, 267, 640, 1280):
+        c = cs_ops.clients_per_step(60, d)
+        assert 1 <= c <= 8
+        assert 2 * c * tile_bytes(d) <= budget
+        assert c == 8 or 2 * (c + 1) * tile_bytes(d) > budget
 
 
 def test_fednew_with_kernel_path_matches_cholesky():

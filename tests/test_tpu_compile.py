@@ -75,14 +75,18 @@ def test_quantize_with_keys_compiles_for_v5e(shape):
     assert "tpu_custom_call" in text
 
 
-def test_client_solve_compiles_for_v5e(shape):
-    """Eq. 9 at w8a width: d=267 pads to a (384, 384) VMEM tile."""
-    n, d = 60, 267
+@pytest.mark.parametrize("n,d", [(60, 267), (15, 267), (2, cs_ops.MAX_DIM)])
+def test_client_solve_compiles_for_v5e(shape, n, d):
+    """Eq. 9 at w8a width (all 60 clients, and the 15 a chip holds on the
+    four-chip mesh: a ragged last block) and at the widest tile. The
+    Hessians reach the one kernel call unpadded: no (n, 384, 384) copy."""
     text = _compiled_text(
         lambda A, b: cs_ops.client_solve(A, b, damping=0.13, interpret=False),
         shape((n, d, d)), shape((n, d)),
     )
-    assert "tpu_custom_call" in text
+    assert text.count("tpu_custom_call") == 1
+    padded = -(-d // 128) * 128
+    assert padded == d or f"f32[{n},{padded},{padded}]" not in text
 
 
 def test_client_solve_refuses_tile_over_vmem_limit():
