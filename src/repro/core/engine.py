@@ -449,9 +449,8 @@ class _Job:
     def program(self, label: str, jitted, *args, static=()):
         """``jitted`` built for these arguments: traced, lowered and
         compiled on first use (one span each), then reused for every call
-        with the same argument types and shardings, as ``jax.jit`` would.
-        (The sharded driver's carry can change sharding after its first
-        block, so the label alone does not name the program.)"""
+        with the same argument types and shardings, as ``jax.jit`` would:
+        the label alone does not name the program."""
         leaves, tree = jax.tree.flatten(args)
         sig = (label, tree, tuple(map(_arg_type, leaves)))
         exe = self.programs.get(sig)
@@ -557,6 +556,10 @@ def _run_sharded(
 
     # Round-0 state is built on the full dataset on the default device, then
     # laid out: per-client rows split over the client axis, rest replicated.
+    # The block's outputs are pinned to that same layout (XLA would otherwise
+    # hand a zero-size client row, the identity codec's state, back
+    # replicated), so every block of one length reuses the first one's
+    # program.
     job = _Job(tracer, next(_JOB_IDS), timings)
     with job.span("init", solver=solver.name):
         state = solver.init(obj, data, key, x0)
@@ -571,7 +574,9 @@ def _run_sharded(
         # draws the same global mask and slices out its own clients.
         carry = (state, part.init_key())
         carry_specs = (state_specs, sh.P())
-    carry = jax.device_put(carry, sh.shardings(carry_specs, mesh))
+    carry_shardings = sh.shardings(carry_specs, mesh)
+    replicated = jax.sharding.NamedSharding(mesh, sh.P())
+    carry = jax.device_put(carry, carry_shardings)
     data = jax.device_put(data, sh.shardings(data_specs, mesh))
 
     obj_ax = obj.with_axis(axis)
@@ -602,7 +607,8 @@ def _run_sharded(
             out_specs=(carry_specs, sh.P()),
             manual_axes=(axis,),
         )
-        return jax.jit(body, donate_argnums=(0,) if donate else ())
+        return jax.jit(body, out_shardings=(carry_shardings, replicated),
+                       donate_argnums=(0,) if donate else ())
 
     chunks = []
     for length in _block_plan(rounds, block_size):

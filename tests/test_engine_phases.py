@@ -146,21 +146,27 @@ k1, k2 = jax.random.split(jax.random.PRNGKey(0))
 data = ClientDataset(features=jax.random.normal(k1, (N, M, D)) / jnp.sqrt(D),
                      labels=jnp.where(jax.random.normal(k2, (N, M)) > 0, 1.0, -1.0))
 obj, mesh = logistic_regression(mu=1e-3), make_client_mesh(4)
-same = True
-for hp in ({{}}, {{"bits": 3}}):  # the identity codec's state is (n, 0) wide
-    s0, m0 = engine.run(_solver(**hp), obj, data, 5, key=KEY, mesh=mesh, block_size=2)
+out = {{"devices": len(mesh.devices.flat)}}
+# The identity codec's state is (n, 0) wide; the 3-bit codec's is (n, d).
+for name, hp, rounds in (("identity", {{}}, 5), ("q3", {{"bits": 3}}, 5),
+                         ("identity-6", {{}}, 6)):
+    s0, m0 = engine.run(_solver(**hp), obj, data, rounds, key=KEY, mesh=mesh, block_size=2)
     log = SpanLog()
-    s1, m1 = engine.run(_solver(**hp), obj, data, 5, key=KEY, mesh=mesh,
+    s1, m1 = engine.run(_solver(**hp), obj, data, rounds, key=KEY, mesh=mesh,
                         block_size=2, tracer=log, timings=[])
-    same &= all(np.array_equal(np.asarray(a), np.asarray(b))
-                for a, b in zip(jax.tree.leaves((s0, m0)), jax.tree.leaves((s1, m1))))
-print(json.dumps({{"devices": len(mesh.devices.flat), "same": same,
-                  "events": [[k, n, a] for k, n, a in log.events],
-                  "labels": [label for label, _ in log.programs]}}))
+    out[name] = {{
+        "same": all(np.array_equal(np.asarray(a), np.asarray(b))
+                    for a, b in zip(jax.tree.leaves((s0, m0)), jax.tree.leaves((s1, m1)))),
+        "events": [[k, n, a] for k, n, a in log.events],
+        "labels": [label for label, _ in log.programs]}}
+print(json.dumps(out))
 """
 
 
-def test_sharded_driver_emits_phase_spans_on_four_devices():
+@pytest.fixture(scope="module")
+def sharded_runs():
+    """Traced and untraced sharded runs on four virtual devices, in a
+    process of its own (the device count is fixed when JAX starts)."""
     tests = os.path.dirname(os.path.abspath(__file__))
     src = os.path.join(os.path.dirname(tests), "src")
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=src,
@@ -169,12 +175,30 @@ def test_sharded_driver_emits_phase_spans_on_four_devices():
                        env=env, capture_output=True, text=True, timeout=600)
     assert p.returncode == 0, p.stderr[-3000:]
     out = json.loads(p.stdout.strip().splitlines()[-1])
-    assert out["devices"] == 4 and out["same"]
+    assert out.pop("devices") == 4
+    return out
+
+
+def test_sharded_driver_emits_phase_spans_on_four_devices(sharded_runs):
+    for case in ("identity", "q3"):
+        out = sharded_runs[case]
+        assert out["same"], case
+        events = [tuple(e) for e in out["events"]]
+        assert [n for k, n, _ in events if k == "enter"] == \
+            expected_phases([True, False, True]), case
+        assert len({a["job"] for _, _, a in events}) == 1, case
+        assert dict(nesting(events))["launch"] == "dispatch", case
+        assert out["labels"] == ["shard_block[2r]", "shard_block[1r]"], case
+
+
+def test_sharded_job_builds_each_block_length_once(sharded_runs):
+    """The identity codec's zero-width state keeps its layout across
+    blocks, so three blocks of two rounds share one program."""
+    out = sharded_runs["identity-6"]
+    assert out["same"]
     events = [tuple(e) for e in out["events"]]
-    assert [n for k, n, _ in events if k == "enter"] == expected_phases([True, False, True])
-    assert len({a["job"] for _, _, a in events}) == 1
-    assert dict(nesting(events))["launch"] == "dispatch"
-    assert out["labels"] == ["shard_block[2r]", "shard_block[1r]"]
+    assert [n for k, n, _ in events if k == "enter"] == expected_phases([True, False, False])
+    assert out["labels"] == ["shard_block[2r]"]
 
 
 def test_first_timing_includes_the_build(problem):
