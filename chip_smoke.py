@@ -72,9 +72,11 @@ def _resolved(spec) -> dict:
     cfg = fednew.FedNewConfig(
         **build._merged_solver_hparams(spec.solver, spec.compression)
     )
+    solve = dispatch.resolve_backend(cfg.resolved_solve_backend)
+    if cfg.matfree:  # the logreg objective gives the glm_hvp kernel its split
+        solve = "cg-on-glm_hvp" if dispatch.use_pallas(solve) else "cg-on-hvp"
     return {
-        "solve": "cg-on-hvp" if cfg.matfree
-        else dispatch.resolve_backend(cfg.resolved_solve_backend),
+        "solve": solve,
         "quant": dispatch.resolve_backend(cfg.resolved_quant_backend)
         if cfg.codec_spec["name"] == "stoch_quant" else "none",
     }

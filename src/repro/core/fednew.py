@@ -38,7 +38,9 @@ the computation-efficient "zeroth Hessian" variant, one factorization ever).
   "matfree" never build H_i: solve with damped conjugate gradients
             (``hvp.cg_solve_clients``) where each matvec is the objective's
             closed-form batched HVP (``Objective.local_hvp``) at the cached
-            per-client anchor. O(n d) state, O(cg_iters n m d) compute — the
+            per-client anchor, or, for a GLM objective on the Pallas solve
+            backend, the ``glm_hvp`` kernel (one read of the features per
+            matvec). O(n d) state, O(cg_iters n m d) compute — the
             only path that survives d ~ 1e5+. ``cg_iters``/``cg_tol`` bound
             the inner iteration; run to convergence (tol ~ 1e-7, generous
             iters) the trajectory matches "dense" to solver tolerance.
@@ -66,12 +68,13 @@ dtype, 32 for float32), ``bits``·d + 32 for Q-FedNew, the codec's exact
 rounds cost no extra bits. Counts are exact Python ints lowered via
 ``quantization.payload_bits_array`` (no int32 wraparound at LM scale).
 
-Both hot loops — the eq. 9 client solve and the eqs. 25-30 quantizer — are
-reached through ``repro.kernels.dispatch``: ``FedNewConfig.backend`` selects
-``auto`` (compiled Pallas on TPU, jnp reference elsewhere), ``pallas``
-(kernel everywhere; interpreter off-TPU), or ``reference``, with per-loop
-overrides ``solve_backend``/``quant_backend``. The legacy ``use_kernel``
-flag remains as an alias for ``solve_backend="pallas"``.
+Both hot loops — the eq. 9 client solve (dense, or matfree HVPs) and the
+eqs. 25-30 quantizer — are reached through ``repro.kernels.dispatch``:
+``FedNewConfig.backend`` selects ``auto`` (compiled Pallas on TPU, jnp
+reference elsewhere), ``pallas`` (kernel everywhere; interpreter off-TPU),
+or ``reference``, with per-loop overrides ``solve_backend``/
+``quant_backend``. The legacy ``use_kernel`` flag remains as an alias for
+``solve_backend="pallas"``.
 """
 
 from __future__ import annotations
@@ -137,14 +140,12 @@ class FedNewConfig:
             raise ValueError(f"cg_iters must be >= 1, got {self.cg_iters}")
         if self.cg_tol < 0:
             raise ValueError(f"cg_tol must be >= 0, got {self.cg_tol}")
-        if self.hessian_repr == "matfree" and (
-            self.use_kernel or self.solve_backend == "pallas"
-        ):
+        if self.hessian_repr == "matfree" and self.use_kernel:
             raise ValueError(
                 "hessian_repr='matfree' solves eq. 9 with CG on HVPs and "
                 "never builds the (n, d, d) Hessians the Pallas client_solve "
-                "kernel consumes; drop use_kernel/solve_backend='pallas' "
-                "(backend= still routes the quantizer)"
+                "kernel consumes; drop use_kernel (solve_backend='pallas' "
+                "routes the matfree HVPs to the glm_hvp kernel)"
             )
 
     @property
@@ -183,10 +184,11 @@ class FedNewConfig:
 
     @property
     def solve_uses_kernel(self) -> bool:
-        """Static (trace-time) routing decision for the eq. 9 solve; also
-        decides whether state.curv caches Cholesky factors (reference) or
-        raw Hessians (the CG kernel applies the damping itself). Matfree
-        mode is kernel-free by construction (pure tree ops)."""
+        """Static (trace-time) routing decision for the dense eq. 9 solve;
+        also decides whether state.curv caches Cholesky factors (reference)
+        or raw Hessians (the CG kernel applies the damping itself). Matfree
+        mode never takes the dense kernel; its HVPs route in
+        ``_matfree_matvec``."""
         if self.matfree:
             return False
         return dispatch.use_pallas(
@@ -412,6 +414,35 @@ def init(
     )
 
 
+def _matfree_matvec(curv, cfg: FedNewConfig, obj: Objective, data):
+    """The matfree CG's matvec: every client's H_i at its anchor in ``curv``.
+
+    An objective that exposes its GLM split (``local_curvature``) on a solve
+    backend that resolves to Pallas gets the ``glm_hvp`` kernel, which reads
+    the features once per call where ``local_hvp``'s two matvecs read them
+    twice. The kernel's copy of the features is made here, outside the CG
+    loop (XLA hoists it out of a scan block too, as the features never
+    change); it is bf16 only where the default matmul precision would hand
+    the chip bf16 operands anyway (``dispatch.matmul_operand_dtype``).
+    Every other case calls ``local_hvp``, lowered as it always was."""
+    backend = cfg.resolved_solve_backend
+    if not (obj.has_curvature
+            and dispatch.use_pallas(dispatch.resolve_backend(backend))):
+        return lambda v: obj.local_hvp(curv, data, v)
+    feats = data.features
+    a = feats.astype(dispatch.matmul_operand_dtype(feats.dtype))
+
+    def matvec(v):
+        # The weights are formed per call, as local_hvp forms them: XLA
+        # hoists their A · anchor product out of the CG loop and merges it
+        # with the gradient's A x. Formed before the loop, that product
+        # compiles on the TPU as a sweep of its own.
+        w = obj.local_curvature(curv, data)
+        return dispatch.glm_hvp(a, w, v, mu=obj.l2, backend=backend)
+
+    return matvec
+
+
 def _local_solve(curv, rhs, cfg: FedNewConfig, obj=None, data=None,
                  with_info=False):
     """(H_i + (alpha+rho) I)^{-1} rhs, batched over clients (eq. 9).
@@ -421,10 +452,10 @@ def _local_solve(curv, rhs, cfg: FedNewConfig, obj=None, data=None,
     iterations-to-tolerance and final residuals on the matfree path, None
     on the direct solves (their residual is solver-exact)."""
     if cfg.matfree:
-        # `curv` holds per-client anchor points; each CG matvec is one call
-        # to the batched closed-form HVP — H_i never exists as a matrix.
+        # `curv` holds per-client anchor points; each CG matvec applies every
+        # client's Hessian at once — H_i never exists as a matrix.
         res = hvp.cg_solve_clients(
-            lambda v: obj.local_hvp(curv, data, v),
+            _matfree_matvec(curv, cfg, obj, data),
             rhs,
             damping=cfg.damping,
             iters=cfg.cg_iters,

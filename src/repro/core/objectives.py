@@ -103,6 +103,7 @@ class Objective:
     local_hessian(x, data) -> (n, d, d)    [optional — flat layout only]
     local_hvp(x, data, v)  -> (n, d)       / per-leaf (n, ...) pytree
                                            [optional]
+    local_curvature(x, data) -> (n, m)     [optional — GLM objectives]
 
     ``local_hvp`` is the matrix-free counterpart of ``local_hessian``: it
     applies every client's Hessian to a per-client vector batch without ever
@@ -113,6 +114,13 @@ class Objective:
     each client may differentiate at its own point. Solvers that need it
     (``hessian_repr="matfree"``, fagh) check :attr:`has_hvp` and fail loudly
     when an objective doesn't provide one.
+
+    ``local_curvature`` is the split a generalized linear model's HVP has
+    (flat layout only): per-row weights ``w`` at the same per-client
+    anchors, such that ``local_hvp(x, data, v)_i == A_i^T (w_i * (A_i v_i))
+    / m + l2 * v_i`` with ``A = data.features`` of ``m`` rows a client. A
+    solver that finds it may apply every client's Hessian in one read of
+    the features (the ``glm_hvp`` kernel) instead of calling ``local_hvp``.
 
     ``local_hessian`` is optional: autodiff model objectives
     (:func:`from_loss_fn`) cannot materialize (d, d) blocks, so solvers on
@@ -133,11 +141,18 @@ class Objective:
     local_hessian: Callable | None = None
     local_hvp: Callable | None = None
     axis_name: str | None = None
+    local_curvature: Callable | None = None
+    l2: float = 0.0  # the ridge term of the local_curvature split
 
     @property
     def has_hvp(self) -> bool:
         """True when the matrix-free ``local_hvp`` oracle is available."""
         return self.local_hvp is not None
+
+    @property
+    def has_curvature(self) -> bool:
+        """True when the GLM split ``local_curvature`` is available."""
+        return self.local_curvature is not None
 
     @property
     def has_hessian(self) -> bool:
@@ -207,12 +222,17 @@ def _logreg_hessian_1(x, A, b, mu):
     return H + mu * jnp.eye(A.shape[1], dtype=A.dtype)
 
 
+def _logreg_curvature_1(x, A, b):
+    """The diagonal D of H(x) = A^T D A / m + mu I: s(1 - s) per row."""
+    z = b * (A @ x)
+    s = jax.nn.sigmoid(z)
+    return s * (1.0 - s)  # (m,) ; b^2 == 1
+
+
 def _logreg_hvp_1(x, v, A, b, mu):
     """H(x) v = A^T (D (A v)) / m + mu v — two matvecs and a diagonal scale,
     O(m d) time and memory; the (d, d) Hessian never exists."""
-    z = b * (A @ x)
-    s = jax.nn.sigmoid(z)
-    w = s * (1.0 - s)  # (m,)
+    w = _logreg_curvature_1(x, A, b)
     return A.T @ (w * (A @ v)) / A.shape[0] + mu * v
 
 
@@ -222,11 +242,14 @@ def logistic_regression(mu: float = 1e-3) -> Objective:
     hess = jax.vmap(partial(_logreg_hessian_1, mu=mu), in_axes=(None, 0, 0))
     # hvp maps per-client anchors AND per-client vectors (see Objective doc)
     hvp = jax.vmap(partial(_logreg_hvp_1, mu=mu), in_axes=(0, 0, 0, 0))
+    curvature = jax.vmap(_logreg_curvature_1, in_axes=(0, 0, 0))
     return Objective(
         local_loss=lambda x, d: loss(x, d.features, d.labels),
         local_grad=lambda x, d: grad(x, d.features, d.labels),
         local_hessian=lambda x, d: hess(x, d.features, d.labels),
         local_hvp=lambda x, d, v: hvp(x, v, d.features, d.labels),
+        local_curvature=lambda x, d: curvature(x, d.features, d.labels),
+        l2=mu,
     )
 
 

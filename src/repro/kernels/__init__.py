@@ -3,11 +3,13 @@
 
   swa_attention — flash sliding-window attention (gemma/mixtral local layers)
   client_solve  — in-VMEM CG for FedNew's eq. 9 damped SPD solve
+  glm_hvp       — matrix-free eq. 9: A^T (w * (A v)) / m + mu v per client
+                  in one read of the features (one call per CG iteration)
   stoch_quant   — Q-FedNew stochastic quantizer (eqs. 25-30), 2-D
                   (clients, blocks) grid with in-kernel tail masking
   slstm_scan    — fused sLSTM recurrence (VMEM-resident state; §Perf pair C)
 
-The two FedNew hot loops (client_solve, stoch_quant) are registered with
+The FedNew hot loops (client_solve, glm_hvp, stoch_quant) are registered with
 the backend-aware dispatch layer (``repro.kernels.dispatch``) and reached
 by the engine through it — call sites select ``auto``/``pallas``/
 ``reference`` instead of importing kernel modules or passing ``interpret=``
@@ -27,6 +29,11 @@ dispatch.register_kernel(
     "client_solve",
     pallas="repro.kernels.client_solve.ops:client_solve",
     reference="repro.kernels.client_solve.ref:client_solve_ref",
+)
+dispatch.register_kernel(
+    "glm_hvp",
+    pallas="repro.kernels.glm_hvp.ops:glm_hvp",
+    reference="repro.kernels.glm_hvp.ref:glm_hvp_ref",
 )
 # the engine's batched Q-FedNew hot loop ...
 dispatch.register_kernel(

@@ -1,9 +1,10 @@
 """Backend-aware dispatch for the FedNew hot-path kernels.
 
-The engine has exactly two byte-moving inner loops — the eq. 9 client solve
-and the eqs. 25-30 stochastic quantizer — and each exists in two
+The engine's byte-moving inner loops — the eq. 9 client solve (dense:
+``client_solve``; matrix-free: the ``glm_hvp`` product each CG iteration
+applies) and the eqs. 25-30 stochastic quantizer — each exist in two
 implementations: a Pallas TPU kernel (``repro.kernels.<name>``) and a jnp
-reference (``repro.core.quantization`` / ``client_solve/ref.py``). This
+reference (``repro.core.quantization`` / ``<name>/ref.py``). This
 module owns the routing between them so no call site ever hardcodes
 ``interpret=True`` (the "silent interpreter" bug) or imports a kernel
 module directly.
@@ -36,6 +37,7 @@ import os
 from typing import Callable, Dict, Optional
 
 import jax
+import jax.numpy as jnp
 
 BACKENDS = ("auto", "pallas", "reference")
 RESOLVED_BACKENDS = ("pallas", "pallas-interpret", "reference")
@@ -92,6 +94,18 @@ def default_interpret() -> bool:
     the old hardcoded ``interpret=True`` defaults that sent TPU users
     through the interpreter silently."""
     return platform() != "tpu"
+
+
+def matmul_operand_dtype(dtype):
+    """The dtype a matmul at JAX's default precision hands the platform's
+    matrix unit for ``dtype`` operands: bfloat16 for float32 on a TPU
+    (unless ``jax_default_matmul_precision`` asks for more), ``dtype``
+    itself elsewhere. A kernel may stream its operand in this dtype without
+    rounding below what the jnp path it replaces computes."""
+    default = jax.config.jax_default_matmul_precision in (None, "default", "bfloat16")
+    if platform() == "tpu" and jnp.dtype(dtype) == jnp.float32 and default:
+        return jnp.bfloat16
+    return jnp.dtype(dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -176,3 +190,14 @@ def client_solve(A, b, *, damping: float, iters: int = 32, backend: str = "auto"
         return fn(A, b, damping=damping, iters=iters,
                   interpret=interpret_flag(resolved))
     return fn(A, b, damping=damping)
+
+
+def glm_hvp(a, w, v, *, mu: float, backend: str = "auto"):
+    """Every client's GLM Hessian applied to its vector: A_i^T (w_i *
+    (A_i v_i)) / m + mu v_i over features ``a`` (n, m, d). The Pallas route
+    reads each row tile of A once for both products; the reference is the
+    two-matvec jnp expression."""
+    fn, resolved = resolve_impl("glm_hvp", backend)
+    if use_pallas(resolved):
+        return fn(a, w, v, mu=mu, interpret=interpret_flag(resolved))
+    return fn(a, w, v, mu=mu)

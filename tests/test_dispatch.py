@@ -12,6 +12,8 @@ from repro.core import quantization as Q
 from repro.core.objectives import logistic_regression
 from repro.data.synthetic import PAPER_DATASETS, make_dataset
 from repro.kernels import dispatch
+from repro.kernels.glm_hvp import ops as glm_ops
+from repro.kernels.glm_hvp import ref as glm_ref
 from repro.launch.mesh import make_client_mesh
 
 KEY = jax.random.PRNGKey(7)
@@ -54,6 +56,17 @@ def test_env_override_resolves_auto(monkeypatch):
     assert dispatch.resolve_backend("pallas") == "pallas-interpret"
 
 
+def test_matmul_operand_dtype_follows_the_default_precision(monkeypatch):
+    """A kernel may stream f32 operands as bf16 only where the jnp path it
+    replaces would round them so: a TPU at JAX's default precision."""
+    assert dispatch.matmul_operand_dtype(jnp.float32) == jnp.float32  # CPU
+    monkeypatch.setattr(dispatch, "platform", lambda: "tpu")
+    assert dispatch.matmul_operand_dtype(jnp.float32) == jnp.bfloat16
+    assert dispatch.matmul_operand_dtype(jnp.bfloat16) == jnp.bfloat16
+    with jax.default_matmul_precision("highest"):
+        assert dispatch.matmul_operand_dtype(jnp.float32) == jnp.float32
+
+
 def test_unknown_backend_rejected():
     with pytest.raises(ValueError, match="unknown kernel backend"):
         dispatch.resolve_backend("cuda")
@@ -63,10 +76,12 @@ def test_unknown_backend_rejected():
 
 def test_registry_serves_both_hot_loops():
     assert set(dispatch.registered_kernels()) >= {
-        "client_solve", "stoch_quant", "stoch_quant.quantize"
+        "client_solve", "glm_hvp", "stoch_quant", "stoch_quant.quantize"
     }
     impl = dispatch.get_impl("stoch_quant", backend="reference")
     assert impl is Q.quantize_with_keys
+    assert dispatch.get_impl("glm_hvp", backend="reference") is glm_ref.glm_hvp_ref
+    assert dispatch.get_impl("glm_hvp", backend="pallas") is glm_ops.glm_hvp
     with pytest.raises(KeyError):
         dispatch.get_impl("flash_attention_v9")
 

@@ -12,6 +12,9 @@ import pytest
 
 from repro.kernels.client_solve import ops as cs_ops
 from repro.kernels.client_solve.ref import client_solve_ref
+from repro.kernels.glm_hvp import ops as glm_ops
+from repro.kernels.glm_hvp.glm_hvp import glm_hvp_pallas
+from repro.kernels.glm_hvp.ref import glm_hvp_ref
 from repro.kernels.stoch_quant import ops as sq_ops
 from repro.kernels.stoch_quant.ref import stoch_quant_ref
 from repro.kernels.stoch_quant.stoch_quant import stoch_quant
@@ -78,6 +81,86 @@ def test_swa_kernel_vs_model_attention():
     np.testing.assert_allclose(
         np.asarray(kern_out), np.asarray(model_out), atol=3e-5, rtol=3e-5
     )
+
+
+# ---------------------------------------------------------------------------
+# glm_hvp
+# ---------------------------------------------------------------------------
+
+
+def _glm_inputs(n, m, d, dtype, seed=0):
+    ka, kw, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
+    a = jax.random.normal(ka, (n, m, d), jnp.float32).astype(dtype)
+    w = 0.25 * jax.random.uniform(kw, (n, m), jnp.float32)
+    v = jax.random.normal(kv, (n, d), jnp.float32)
+    return a, w, v
+
+
+@pytest.mark.parametrize(
+    "n,m,d,rows,dtype",
+    [
+        (1, 37, 267, 16, jnp.float32),  # one client, ragged m and d
+        (3, 37, 267, 16, jnp.float32),
+        (3, 1012, 131, None, jnp.float32),  # rcv1's m: one 1,016-row tile
+        (2, 45, 1300, 16, jnp.bfloat16),  # d past one lane chunk, ragged tail
+        (3, 21, 2048, 8, jnp.float32),  # d a whole number of chunks
+        (1, 64, 128, 16, jnp.float32),  # m a whole number of tiles
+        (2, 29, 24, 16, jnp.float32),  # d under one lane group
+    ],
+)
+def test_glm_hvp_matches_ref(n, m, d, rows, dtype):
+    """``rows`` forces small row tiles (None: the wrapper's choice)."""
+    a, w, v = _glm_inputs(n, m, d, dtype)
+    if rows is None:
+        got = glm_ops.glm_hvp(a, w, v, mu=1e-3, interpret=True)
+    else:
+        got = glm_hvp_pallas(a, w, v, mu=1e-3, rows_per_step=rows, interpret=True)
+    ref = glm_hvp_ref(a, w, v, mu=1e-3)
+    scale = float(jnp.max(jnp.abs(ref)))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-5,
+                               atol=1e-5 * scale)
+
+
+def test_glm_hvp_masks_the_rows_past_m():
+    """The rows of the last tile past m hold whatever the DMA left there;
+    the interpreter fills them with NaN (checked first), and NaN * 0 is
+    NaN, so a result without NaN shows the kernel masks those rows of A
+    itself and not only their weights."""
+    from jax.experimental import pallas as pl
+
+    def count_nan(x_ref, o_ref):
+        o_ref[...] = jnp.sum(jnp.isnan(x_ref[...]).astype(jnp.float32),
+                             axis=0, keepdims=True)
+
+    nans = pl.pallas_call(
+        count_nan, grid=(1,), in_specs=[pl.BlockSpec((8, 128), lambda i: (0, 0))],
+        out_specs=pl.BlockSpec((1, 128), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((1, 128), jnp.float32), interpret=True,
+    )(jnp.zeros((5, 128), jnp.float32))
+    assert np.all(np.asarray(nans) == 3)
+
+    a, w, v = _glm_inputs(2, 37, 300, jnp.float32, seed=3)
+    got = np.asarray(glm_hvp_pallas(a, w, v, mu=1e-3, rows_per_step=16, interpret=True))
+    assert np.all(np.isfinite(got))
+    np.testing.assert_allclose(got, np.asarray(glm_hvp_ref(a, w, v, mu=1e-3)),
+                               rtol=1e-5, atol=1e-5 * np.max(np.abs(got)))
+
+
+def test_glm_hvp_rows_per_step():
+    """A bf16 tile of 16-row multiples near TILE_BYTES and of at least
+    MIN_ROWS rows, covering m in as few tiles as that allows: 13 tiles of
+    80 rows at rcv1, 16 of 64 at E2006; f32 tiles are 8-row multiples."""
+    assert glm_ops.rows_per_step(1012, 47236, 2) == 80
+    assert glm_ops.rows_per_step(969, 150360, 2) == 64
+    assert glm_ops.rows_per_step(37, 267, 4) == 40
+    for m, d, size in [(1012, 47236, 2), (969, 150360, 2), (829, 267, 4), (5, 10**6, 2)]:
+        tm = glm_ops.rows_per_step(m, d, size)
+        pack = 8 * (4 // size)
+        assert tm % pack == 0
+        assert (tm <= glm_ops.MIN_ROWS
+                or tm * -(-d // 128) * 128 * size <= glm_ops.TILE_BYTES)
+        tiles = -(-m // tm)
+        assert (tiles - 1) * tm < m <= tiles * tm
 
 
 # ---------------------------------------------------------------------------

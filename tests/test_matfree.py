@@ -25,6 +25,7 @@ import pytest
 from repro import api
 from repro.core import engine, fednew, hvp
 from repro.core.objectives import (
+    ClientDataset,
     Objective,
     logistic_regression,
     quadratic,
@@ -72,6 +73,22 @@ def test_logreg_hvp_honors_per_client_anchors(logreg_267):
     ])
     free = obj.local_hvp(anchors, data, v)
     np.testing.assert_allclose(free, per_client, rtol=1e-4, atol=1e-5)
+
+
+def test_logreg_curvature_split_is_its_hvp(logreg_267):
+    """local_curvature's weights rebuild local_hvp: A^T (w * (A v)) / m +
+    l2 v at per-client anchors; the objectives that have no such split
+    say so."""
+    obj, data = logreg_267
+    n, m = data.n_clients, data.features.shape[1]
+    x = 0.1 * jax.random.normal(jax.random.PRNGKey(1), (n, D))
+    v = jax.random.normal(jax.random.PRNGKey(2), (n, D))
+    w = obj.local_curvature(x, data)
+    assert w.shape == (n, m) and obj.l2 == 1e-3
+    A = data.features
+    split = jnp.einsum("nmd,nm->nd", A, w * jnp.einsum("nmd,nd->nm", A, v)) / m + obj.l2 * v
+    np.testing.assert_allclose(split, obj.local_hvp(x, data, v), rtol=1e-5, atol=1e-6)
+    assert obj.has_curvature and not quadratic().has_curvature
 
 
 def test_quadratic_hvp_is_P_apply():
@@ -161,6 +178,48 @@ def test_matfree_matches_dense_trajectory_d267(logreg_267, mesh_devices):
     assert rel <= 1e-5, f"relative loss gap {rel:.2e} > 1e-5"
 
 
+@pytest.mark.parametrize("mesh_devices", [None, 1], ids=["scan", "shard_map"])
+@pytest.mark.parametrize("d", [D, 300])
+def test_matfree_glm_kernel_matches_reference(mesh_devices, d):
+    """solve_backend="pallas" (the glm_hvp kernel, interpreted here) runs
+    the same matfree job as "reference" (local_hvp): losses and the model
+    agree within the dense-trajectory tolerance, at w8a's d and at a d
+    with a ragged last lane group."""
+    spec = synthetic.DatasetSpec(
+        "custom", n_clients=4, samples_per_client=37, dim=d, sparse=True
+    )
+    obj, data = logistic_regression(1e-3), synthetic.make_dataset(spec, KEY)
+    mesh = make_client_mesh(mesh_devices) if mesh_devices else None
+    runs = {}
+    for backend in ("reference", "pallas"):
+        hp = dict(MATFREE_HP, cg_iters=50, solve_backend=backend)
+        st, m = engine.run(
+            engine.get_solver("fednew", **hp), obj, data, 4,
+            key=jax.random.PRNGKey(0), mesh=mesh,
+        )
+        runs[backend] = (np.asarray(m.loss), np.asarray(st.x))
+    (ref_loss, ref_x), (got_loss, got_x) = runs["reference"], runs["pallas"]
+    assert np.max(np.abs(got_loss - ref_loss) / np.abs(ref_loss)) <= 1e-5
+    assert np.linalg.norm(got_x - ref_x) <= 1e-5 * np.linalg.norm(ref_x)
+
+
+def test_matfree_auto_on_cpu_lowers_local_hvp(logreg_267):
+    """backend="auto" on the CPU keeps the local_hvp path, lowered exactly
+    as "reference" lowers it: no glm_hvp kernel in the program."""
+    obj, data = logreg_267
+
+    def lowered(**kw):
+        cfg = fednew.FedNewConfig(**{**MATFREE_HP, "cg_iters": 4}, **kw)
+        state = fednew.init(obj, data, cfg, KEY)
+        step = jax.jit(lambda s: fednew.step(s, obj, data, cfg))
+        return step.lower(state).as_text()
+
+    auto = lowered()
+    assert "glm_hvp" not in auto
+    assert auto == lowered(solve_backend="reference")
+    assert "glm_hvp" in lowered(solve_backend="pallas")
+
+
 def test_matfree_qfednew_and_hessian_period(logreg_267):
     """Q-FedNew composes with matfree, and hessian_period=0 freezes the
     anchor at x^0 (the r=0 zeroth-Hessian variant, now O(n d) state)."""
@@ -248,8 +307,13 @@ def test_config_validation():
         fednew.FedNewConfig(hessian_repr="matfree", cg_tol=-1.0)
     with pytest.raises(ValueError, match="matfree"):
         fednew.FedNewConfig(hessian_repr="matfree", use_kernel=True)
-    with pytest.raises(ValueError, match="matfree"):
-        fednew.FedNewConfig(hessian_repr="matfree", solve_backend="pallas")
+    # solve_backend="pallas" on matfree routes the HVPs to the glm_hvp kernel
+    cfg = fednew.FedNewConfig(hessian_repr="matfree", solve_backend="pallas")
+    assert not cfg.solve_uses_kernel  # no dense Hessians are cached
+    obj = logistic_regression(1e-3)
+    curv, data = jnp.zeros((2, 3)), ClientDataset(jnp.ones((2, 4, 3)), jnp.ones((2, 4)))
+    text = str(jax.make_jaxpr(fednew._matfree_matvec(curv, cfg, obj, data))(curv))
+    assert "glm_hvp" in text and "pallas_call" in text
 
 
 def test_matfree_requires_hvp_oracle(logreg_267):

@@ -3,7 +3,8 @@
 Interpret mode (every other kernel test) accepts block shapes and VMEM
 footprints the TPU compiler refuses, so these tests compile each dispatched
 kernel ahead of time for a *described* v5e chip at the main path's widths
-(w8a: 60 clients x d=267; matrix-free scale: 16 clients x d=1e5) and check
+(w8a: 60 clients x d=267; matrix-free scale: 16 clients x d=1e5, and the
+matfree HVP at rcv1's and E2006's per-chip features) and check
 that the Pallas kernel is in the program (``tpu_custom_call``). Nothing
 runs, so no chip is needed; where no TPU topology can be described the
 tests skip.
@@ -19,6 +20,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.client_solve import ops as cs_ops
+from repro.kernels.glm_hvp import ops as glm_ops
 from repro.kernels.stoch_quant import ops as sq_ops
 from repro.kernels.stoch_quant.stoch_quant import stoch_quant
 
@@ -100,3 +102,17 @@ def test_client_solve_refuses_tile_over_vmem_limit():
             lambda A, b: cs_ops.client_solve(A, b, damping=1.0, interpret=False),
             A, b,
         )
+
+
+@pytest.mark.parametrize("n,m,d", [(20, 1012, 47236), (5, 969, 150360)])
+def test_glm_hvp_compiles_for_v5e(shape, n, m, d):
+    """The matfree HVP at rcv1's 20 clients and E2006's 5 a chip, on the
+    bf16 features: whole-d row tiles at a d that is no multiple of 128, a
+    ragged last row tile, in one custom call named after the wrapper (the
+    name the benchmark's trace reader looks for)."""
+    text = _compiled_text(
+        lambda a, w, v: glm_ops.glm_hvp(a, w, v, mu=1e-3, interpret=False),
+        shape((n, m, d), jnp.bfloat16), shape((n, m)), shape((n, d)),
+    )
+    assert text.count("tpu_custom_call") == 1
+    assert "%glm_hvp" in text
