@@ -26,6 +26,7 @@ from repro.comm.codecs import (
     build_codec,
     client_keys,
     codec_names,
+    encode_decode,
     encode_decode_tree,
     encode_decode_tree_one,
     init_state_tree,
@@ -52,6 +53,7 @@ __all__ = [
     "codec_names",
     "normalize_spec",
     "register_codec",
+    "encode_decode",
     "encode_decode_tree",
     "encode_decode_tree_one",
     "init_state_tree",

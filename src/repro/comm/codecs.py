@@ -434,10 +434,9 @@ class BitScheduleCodec(Codec):
 def client_keys(sub, n_local: int, axis_name, n_global_clients):
     """Per-client PRNG keys for a stochastic codec, identical across
     schedules: split for ALL clients and slice this shard's rows, so the
-    client-axis layout never changes the randomness. (Historically
-    ``fednew._client_keys``; shared here because every solver that encodes
-    through an RNG codec — fednew, fednl — needs the same device-count
-    invariance.)"""
+    client-axis layout never changes the randomness. Every solver that
+    encodes through an RNG codec (fednew, fednl) needs this device-count
+    invariance."""
     if axis_name is None:
         return jax.random.split(sub, n_local)
     if n_global_clients is None:
@@ -515,7 +514,7 @@ def build_codec(spec: CodecSpec, *, backend: str = "auto") -> Codec:
 
 
 # ---------------------------------------------------------------------------
-# pytree helpers (the LM-scale fednew_hf route)
+# one round of the codec, and its pytree helpers
 # ---------------------------------------------------------------------------
 
 
@@ -577,6 +576,24 @@ def encode_decode_tree(codec: Codec, key, tree, state_tree, *, step=0):
         tx.append(y_tx.reshape(leaf.shape).astype(leaf.dtype))
         states.append(new_state.reshape(p.shape).astype(p.dtype))
     return jax.tree.unflatten(treedef, tx), jax.tree.unflatten(treedef, states)
+
+
+def encode_decode(codec: Codec, key, y, state, *, step=0, axis_name=None,
+                  n_global_clients=None):
+    """One round of the uplink codec, ``(y_tx, new_state)``, for either
+    layout of the per-client directions ``y``. A bare ``(n, d)`` array is
+    one message per client, keyed by :func:`client_keys` (the same keys
+    under every schedule); a pytree goes through :func:`encode_decode_tree`
+    and its per-leaf key schedule, on one device only. ``key`` is the
+    round's subkey, unused by a deterministic codec."""
+    if not jax.tree_util.treedef_is_leaf(jax.tree.structure(y)):
+        return encode_decode_tree(codec, key, y, state, step=step)
+    keys = None
+    if codec.needs_rng:
+        keys = client_keys(key, y.shape[0], axis_name, n_global_clients)
+    wire = codec.encode(keys, y, state, step)
+    y_tx = codec.decode(wire, state, step)
+    return y_tx, codec.update_state(y_tx, y, state, step)
 
 
 def encode_decode_tree_one(codec: Codec, key, tree, state_tree, *, step=0):

@@ -13,16 +13,18 @@ State layout mirrors Algorithm 1:
                                   evaluated at; no d x d array ever exists
   comm   (n, w)    per-client compression-codec state (``repro.comm``): the
                    previously-quantized vector for stoch_quant (Q-FedNew's
-                   ŷ, historically the ``y_hat`` field), the error-feedback
-                   residual for topk, width 0 for the identity codec
+                   ŷ), the error-feedback residual for topk, width 0 for the
+                   identity codec
 
-Pytree layout: when ``init`` receives a param *pytree* as ``x0`` (a model
-objective — ``objectives.from_loss_fn``), every field generalizes leaf-wise:
-x/y are param trees, lam/curv stack a leading client axis onto every leaf
-(curv holds per-client anchor trees; matfree is mandatory), comm holds one
-``(n, width)`` codec-state array per leaf and the uplink applies the codec
-per (client, leaf) via ``comm.encode_decode_tree``. The flat path below is
-dispatched away from (``objectives.is_param_tree``) and stays bit-exact.
+One step serves both layouts of ``x``: a model's param pytree
+(``objectives.from_loss_fn``; ``hessian_repr="matfree"``, one device) or
+the flat ``(d,)`` vector, its one-leaf case. lam/curv stack a client axis
+onto every leaf and comm holds one codec state per leaf. On the flat
+vector the metric norms stay ``jnp.linalg.norm`` and the bit metric the
+codec's ``payload_bits_metric`` on d: ``hvp.tree_norm`` and
+``comm.tree_payload_bits_metric`` give the same numbers for one leaf but
+compile to another program, and the flat program is the one the
+benchmark measures.
 
 The Hessian refresh rate r from the experiments maps to ``hessian_period``:
 r=1 -> 1, r=0.1 -> 10, r=0 -> 0 (never refresh; factor from x^0 is kept —
@@ -73,8 +75,7 @@ eqs. 25-30 quantizer — are reached through ``repro.kernels.dispatch``:
 ``FedNewConfig.backend`` selects ``auto`` (compiled Pallas on TPU, jnp
 reference elsewhere), ``pallas`` (kernel everywhere; interpreter off-TPU),
 or ``reference``, with per-loop overrides ``solve_backend``/
-``quant_backend``. The legacy ``use_kernel`` flag remains as an alias for
-``solve_backend="pallas"``.
+``quant_backend``.
 """
 
 from __future__ import annotations
@@ -102,7 +103,6 @@ class FedNewConfig:
     alpha: float = 1.0
     hessian_period: int = 1  # 0 => never refresh (r = 0)
     bits: Optional[int] = None  # sugar for codec={"name":"stoch_quant","bits":b}
-    use_kernel: bool = False  # legacy alias for solve_backend="pallas"
     backend: str = "auto"  # "auto" | "pallas" | "reference" (both hot loops)
     solve_backend: Optional[str] = None  # per-loop override, eq. 9
     quant_backend: Optional[str] = None  # per-loop override, eqs. 25-30
@@ -140,13 +140,6 @@ class FedNewConfig:
             raise ValueError(f"cg_iters must be >= 1, got {self.cg_iters}")
         if self.cg_tol < 0:
             raise ValueError(f"cg_tol must be >= 0, got {self.cg_tol}")
-        if self.hessian_repr == "matfree" and self.use_kernel:
-            raise ValueError(
-                "hessian_repr='matfree' solves eq. 9 with CG on HVPs and "
-                "never builds the (n, d, d) Hessians the Pallas client_solve "
-                "kernel consumes; drop use_kernel (solve_backend='pallas' "
-                "routes the matfree HVPs to the glm_hvp kernel)"
-            )
 
     @property
     def damping(self) -> float:
@@ -154,11 +147,7 @@ class FedNewConfig:
 
     @property
     def resolved_solve_backend(self) -> str:
-        if self.solve_backend is not None:
-            return self.solve_backend
-        if self.backend == "auto" and self.use_kernel:
-            return "pallas"
-        return self.backend
+        return self.solve_backend if self.solve_backend is not None else self.backend
 
     @property
     def resolved_quant_backend(self) -> str:
@@ -293,9 +282,9 @@ def _diag_metrics(
     mask,
     axis_name,
 ) -> StepMetricsDiag:
-    """The ``diag_*`` catalogue from one round's intermediates — shared by
-    the flat and pytree step paths (every expression is tree-generic: a flat
-    ``(n, d)`` stack is just a one-leaf tree)."""
+    """The ``diag_*`` catalogue from one round's intermediates (every
+    expression is tree-generic: a flat ``(n, d)`` stack is a one-leaf
+    tree)."""
     primal = _diag_mean(
         hvp.client_norms(jax.tree.map(
             lambda t, yl: t - jnp.broadcast_to(yl, t.shape), y_i_tx, y
@@ -355,7 +344,7 @@ def _fresh_curv(obj: Objective, x, data, cfg: FedNewConfig, n_local: int):
     """The curvature cache a client that saw iterate ``x`` would hold:
     factors/Hessians in dense mode, the anchor point itself in matfree."""
     if cfg.matfree:
-        return jnp.broadcast_to(x, (n_local,) + x.shape)
+        return admm.bcast_clients(x, n_local)
     return _factorize(obj, x, data, cfg)
 
 
@@ -374,41 +363,25 @@ def _check_tree_mode(cfg: FedNewConfig, axis_name=None) -> None:
         )
 
 
-def _init_tree(
-    obj: Objective, data, cfg: FedNewConfig, key: jax.Array, x0
-) -> FedNewState:
-    """Pytree-layout init: x0 IS the model's param pytree (required — zeros
-    can't be conjured without the tree structure); per-client state stacks a
-    client axis onto every leaf, the codec state is per-leaf."""
-    _check_tree_mode(cfg)
-    n = data.n_clients
-    return FedNewState(
-        x=x0,
-        y=jax.tree.map(jnp.zeros_like, x0),
-        lam=admm.stack_zeros(x0, n),
-        curv=admm.bcast_clients(x0, n),
-        comm=comm.init_state_tree(cfg.build_codec(), n, x0),
-        key=key,
-        step=jnp.zeros((), jnp.int32),
-    )
-
-
 def init(
     obj: Objective, data: ClientDataset, cfg: FedNewConfig, key: jax.Array, x0=None
 ) -> FedNewState:
+    """The round-0 state; ``x0`` is a flat ``(d,)`` vector (zeros when
+    None) or a param pytree, which has no default."""
     _check_matfree(obj, cfg)
     if x0 is not None and is_param_tree(x0):
-        return _init_tree(obj, data, cfg, key, x0)
-    d = data.dim
+        _check_tree_mode(cfg)
+        x = x0
+    else:
+        dtype = data.features.dtype if data.features.dtype in (jnp.float32, jnp.float64) else jnp.float32
+        x = jnp.zeros((data.dim,), dtype) if x0 is None else jnp.asarray(x0, dtype)
     n = data.n_clients
-    dtype = data.features.dtype if data.features.dtype in (jnp.float32, jnp.float64) else jnp.float32
-    x = jnp.zeros((d,), dtype) if x0 is None else jnp.asarray(x0, dtype)
     return FedNewState(
         x=x,
-        y=jnp.zeros((d,), dtype),
-        lam=jnp.zeros((n, d), dtype),
+        y=jax.tree.map(jnp.zeros_like, x),
+        lam=admm.stack_zeros(x, n),
         curv=_fresh_curv(obj, x, data, cfg, n),
-        comm=cfg.build_codec().init_state(n, d, dtype),
+        comm=comm.init_state_tree(cfg.build_codec(), n, x),
         key=key,
         step=jnp.zeros((), jnp.int32),
     )
@@ -473,114 +446,77 @@ def _local_solve(curv, rhs, cfg: FedNewConfig, obj=None, data=None,
     return (y, None) if with_info else y
 
 
-def _mask_rows(mask, new, old):
-    """Per-client select: sampled clients take the new row, the rest keep
-    their stale state (lam, codec state, cached factors)."""
-    m = mask.reshape(mask.shape + (1,) * (new.ndim - 1))
-    return jnp.where(m > 0, new, old)
-
-
-# Per-client codec PRNG keys (device-count invariant); now shared across
-# solvers as ``repro.comm.client_keys`` — this alias keeps the historical
-# import site.
-_client_keys = comm.client_keys
-
-
-def _step_tree(
-    state: FedNewState,
+def client_half(
+    state,
     obj: Objective,
-    data,
+    data: ClientDataset,
     cfg: FedNewConfig,
+    *,
+    axis_name: Optional[str] = None,
+    n_global_clients: Optional[int] = None,
     mask: Optional[jax.Array] = None,
 ):
-    """One outer round over a param *pytree* — the same Algorithm 1 flow as
-    the flat path below, with every (n, d) stack generalized to per-leaf
-    (n, ...) trees: matfree CG on autodiff HVPs for eq. 9, per-leaf codec
-    application on the uplink (``comm.encode_decode_tree``), tree-generic
-    ADMM aggregation/dual update, per-leaf exact bit accounting. The flat
-    path is never routed here, so its lowering (and every bit-exactness pin)
-    is untouched."""
+    """The clients' half of Algorithm 1: refresh the curvature, solve eq. 9
+    and pass the direction through the uplink codec; nothing crosses
+    clients. ``obj`` is already bound to ``axis_name``; ``state`` is any
+    state with FedNew's x/y/lam/curv/comm/key/step fields (the async
+    solver's buffer state too). Returns ``(curv, y_i, y_i_tx, comm, key,
+    cg_info)``: the refreshed curvature, the eq. 9 directions, their PS-side
+    reconstruction, the advanced codec state, the run key after this
+    round's split, and the CG result (matfree with diagnostics; else
+    None)."""
+    _check_matfree(obj, cfg)
     n_local = jax.tree.leaves(state.lam)[0].shape[0]
-    # -- local Hessian refresh: re-anchor sampled clients' curvature at x^k --
+    # -- local Hessian refresh (pure client-side compute; no communication) --
     if cfg.hessian_period > 0:
         with jax.named_scope("fednew.hessian"):
             refresh = (state.step % cfg.hessian_period) == 0
             curv = jax.lax.cond(
                 refresh,
-                lambda: admm.bcast_clients(state.x, n_local),
+                lambda: _fresh_curv(obj, state.x, data, cfg, n_local),
                 lambda: state.curv,
             )
             if mask is not None:
+                # Only sampled clients saw x^k; the rest keep the stale factor.
                 curv = admm.mask_client_rows(mask, curv, state.curv)
     else:
         curv = state.curv
 
     with jax.named_scope("fednew.grad"):
-        g_i = obj.local_grad(state.x, data)  # per-leaf (n, ...), never sent
+        g_i = obj.local_grad(state.x, data)  # (n, ...) — never transmitted
         rhs = admm.admm_rhs(
             g_i, state.lam, admm.bcast_clients(state.y, n_local), cfg.rho
         )
 
-    # -- eq. 9: batched damped CG on the autodiff HVP oracle ----------------
+    # -- eq. 9: client sub-problem solve ------------------------------------
     with jax.named_scope("fednew.eq9"):
-        cg_res = hvp.cg_solve_clients(
-            lambda v: obj.local_hvp(curv, data, v),
-            rhs,
-            damping=cfg.damping,
-            iters=cfg.cg_iters,
-            tol=cfg.cg_tol,
-            track_iters=cfg.diagnostics,
-        )
-        y_i = cg_res.x
+        if cfg.diagnostics:
+            y_i, cg_info = _local_solve(curv, rhs, cfg, obj, data,
+                                        with_info=True)
+        else:
+            y_i, cg_info = _local_solve(curv, rhs, cfg, obj, data), None
 
-    # -- uplink compression: the codec applied leaf-wise --------------------
+    # -- uplink compression (repro.comm codec) ------------------------------
+    # Encode client-side, aggregate the PS-side decode: eq. 13 and the dual
+    # update run on the *reconstructed* y_i so the sum-lambda invariant holds
+    # (every client knows its own reconstruction). Deterministic codecs never
+    # touch the PRNG, so plain FedNew's key never moves.
     codec = cfg.build_codec()
     with jax.named_scope("fednew.codec"):
         if codec.needs_rng:
             key, sub = jax.random.split(state.key)
         else:
-            key, sub = state.key, state.key  # deterministic codecs: unused
-        y_i_tx, comm_state = comm.encode_decode_tree(
-            codec, sub, y_i, state.comm, step=state.step
+            key, sub = state.key, None
+        y_i_tx, comm_state = comm.encode_decode(
+            codec, sub, y_i, state.comm, step=state.step,
+            axis_name=axis_name, n_global_clients=n_global_clients,
         )
         if mask is not None:
+            # Sampled clients advance their codec state (ŷ / EF residual); the
+            # rest encoded nothing this round and keep it stale. Their y_i_tx
+            # rows are irrelevant: the weighted aggregates zero them out.
             comm_state = admm.mask_client_rows(mask, comm_state, state.comm)
-
-    with jax.named_scope("fednew.aggregate"):
-        # -- eqs. 13 + 12: the ONLY communication + dual update -------------
-        y = admm.tree_mean_clients(y_i_tx, None, weights=mask)
-        lam = admm.dual_update(
-            state.lam, y_i_tx, admm.bcast_clients(y, n_local), cfg.rho,
-            weights=mask,
-        )
-
-        # -- exact per-leaf uplink accounting -------------------------------
-        bits = comm.tree_payload_bits_metric(codec, y, state.step)
-        if mask is not None:
-            from repro.core import participation
-
-            bits = participation.masked_bits_metric(bits, mask, None)
-
-        x = jax.tree.map(lambda p, yl: p - yl, state.x, y)  # eq. 14
-
-    new_state = FedNewState(
-        x=x, y=y, lam=lam, curv=curv, comm=comm_state, key=key,
-        step=state.step + 1,
-    )
-    with jax.named_scope("fednew.eval"):
-        metrics = StepMetrics(
-            loss=obj.global_loss(x, data),
-            grad_norm=hvp.tree_norm(obj.global_grad(x, data)),
-            uplink_bits_per_client=bits,
-            dual_sum_residual=admm.dual_sum_residual(lam),
-            direction_norm=hvp.tree_norm(y),
-        )
-        if cfg.diagnostics:
-            metrics = _diag_metrics(
-                state, cfg, metrics, y_i=y_i, y_i_tx=y_i_tx, y=y, curv=curv,
-                cg_info=cg_res, mask=mask, axis_name=None,
-            )
-    return new_state, metrics
+    return curv, y_i, y_i_tx, comm_state, key, cg_info
 
 
 def step(
@@ -613,101 +549,58 @@ def step(
     Compression routes through the config's ``repro.comm`` codec: the step
     encodes each client's direction (per-client keys only when the codec is
     stochastic — plain FedNew never touches the PRNG), aggregates the PS-side
-    ``decode`` of the wire payload, and updates ``state.comm``. The identity
-    codec reproduces pre-codec FedNew and ``bits=b`` (the stoch_quant codec)
-    reproduces Q-FedNew bit for bit (pinned in tests/test_comm.py).
+    ``decode`` of the wire payload, and updates ``state.comm``. ``bits=b``
+    is the stoch_quant codec, so Q-FedNew is this step with that codec.
     """
+    flat = not is_param_tree(state.x)
+    if not flat:
+        _check_tree_mode(cfg, axis_name)
     # Engine contract: a sharded caller passes an obj already bound to this
     # axis (with_axis is idempotent then); the rebind here covers direct
     # callers, whose metrics would otherwise silently aggregate shard-local.
-    if is_param_tree(state.x):
-        _check_tree_mode(cfg, axis_name)
-        _check_matfree(obj, cfg)
-        return _step_tree(state, obj, data, cfg, mask)
     if axis_name is not None:
         obj = obj.with_axis(axis_name)
-    _check_matfree(obj, cfg)
-    n_local = state.lam.shape[0]
-    # -- local Hessian refresh (pure client-side compute; no communication) --
-    if cfg.hessian_period > 0:
-        with jax.named_scope("fednew.hessian"):
-            refresh = (state.step % cfg.hessian_period) == 0
-            curv = jax.lax.cond(
-                refresh,
-                lambda: _fresh_curv(obj, state.x, data, cfg, n_local),
-                lambda: state.curv,
-            )
-            if mask is not None:
-                # Only sampled clients saw x^k; the rest keep the stale factor.
-                curv = _mask_rows(mask, curv, state.curv)
-    else:
-        curv = state.curv
-
-    with jax.named_scope("fednew.grad"):
-        g_i = obj.local_grad(state.x, data)  # (n, d) — never transmitted
-        rhs = admm.admm_rhs(
-            g_i, state.lam, jnp.broadcast_to(state.y, g_i.shape), cfg.rho
-        )
-
-    # -- eq. 9: client sub-problem solve ------------------------------------
-    with jax.named_scope("fednew.eq9"):
-        if cfg.diagnostics:
-            y_i, cg_info = _local_solve(curv, rhs, cfg, obj, data,
-                                        with_info=True)
-        else:
-            y_i = _local_solve(curv, rhs, cfg, obj, data)
-
-    # -- uplink compression (repro.comm codec) ------------------------------
-    # Encode client-side, aggregate the PS-side decode: eq. 13 and the dual
-    # update run on the *reconstructed* y_i so the sum-lambda invariant holds
-    # (every client knows its own reconstruction). Deterministic codecs never
-    # touch the PRNG — plain FedNew's key stays bit-frozen, as it always was.
+    n_local = jax.tree.leaves(state.lam)[0].shape[0]
+    curv, y_i, y_i_tx, comm_state, key, cg_info = client_half(
+        state, obj, data, cfg, axis_name=axis_name,
+        n_global_clients=n_global_clients, mask=mask,
+    )
     codec = cfg.build_codec()
-    with jax.named_scope("fednew.codec"):
-        if codec.needs_rng:
-            key, sub = jax.random.split(state.key)
-            keys = _client_keys(sub, y_i.shape[0], axis_name, n_global_clients)
-        else:
-            key, keys = state.key, None
-        wire = codec.encode(keys, y_i, state.comm, state.step)
-        y_i_tx = codec.decode(wire, state.comm, state.step)
-        comm_state = codec.update_state(y_i_tx, y_i, state.comm, state.step)
-        if mask is not None:
-            # Sampled clients advance their codec state (ŷ / EF residual); the
-            # rest encoded nothing this round and keep it stale. Their y_i_tx
-            # rows are irrelevant: the weighted aggregates zero them out.
-            comm_state = _mask_rows(mask, comm_state, state.comm)
 
     with jax.named_scope("fednew.aggregate"):
         # -- eqs. 13 + 12: the ONLY communication + dual update -------------
         y = admm.tree_mean_clients(y_i_tx, axis_name, weights=mask)
         lam = admm.dual_update(
-            state.lam, y_i_tx, jnp.broadcast_to(y, y_i_tx.shape), cfg.rho,
+            state.lam, y_i_tx, admm.bcast_clients(y, n_local), cfg.rho,
             weights=mask,
         )
 
         # -- exact uplink accounting ----------------------------------------
-        bits = codec.payload_bits_metric(
-            data.dim, word_bits(y_i_tx), state.step
-        )
+        if flat:
+            bits = codec.payload_bits_metric(
+                data.dim, word_bits(y_i_tx), state.step
+            )
+        else:
+            bits = comm.tree_payload_bits_metric(codec, y, state.step)
         if mask is not None:
             from repro.core import participation
 
             bits = participation.masked_bits_metric(bits, mask, axis_name)
 
-        x = state.x - y  # outer Newton step (eq. 14)
+        x = jax.tree.map(lambda p, yl: p - yl, state.x, y)  # eq. 14
 
     new_state = FedNewState(
         x=x, y=y, lam=lam, curv=curv, comm=comm_state, key=key,
         step=state.step + 1,
     )
+    norm = jnp.linalg.norm if flat else hvp.tree_norm
     with jax.named_scope("fednew.eval"):
         metrics = StepMetrics(
             loss=obj.global_loss(x, data),
-            grad_norm=jnp.linalg.norm(obj.global_grad(x, data)),
+            grad_norm=norm(obj.global_grad(x, data)),
             uplink_bits_per_client=bits,
             dual_sum_residual=admm.dual_sum_residual(lam, axis_name),
-            direction_norm=jnp.linalg.norm(y),
+            direction_norm=norm(y),
         )
         if cfg.diagnostics:
             metrics = _diag_metrics(

@@ -26,12 +26,12 @@ quantized correction, EF residual) rides the scan/shard_map carry exactly
 like FedNew's ``comm`` field.
 
 Participation semantics mirror a real fleet: only sampled clients compute a
-correction and advance ``H_i``/codec state (``_mask_rows``); the PS-side
-mean-of-estimates is over ALL clients — stale estimates included, because
-the PS still *holds* an offline client's last estimate. The gradient mean
-is masked (only sampled clients transmit this round). An all-empty round is
-a frozen no-op: g aggregates to 0, so the projected solve returns 0, and
-every per-client row keeps its stale value.
+correction and advance ``H_i``/codec state (``admm.mask_client_rows``); the
+PS-side mean-of-estimates is over ALL clients — stale estimates included,
+because the PS still *holds* an offline client's last estimate. The
+gradient mean is masked (only sampled clients transmit this round). An
+all-empty round is a frozen no-op: g aggregates to 0, so the projected
+solve returns 0, and every per-client row keeps its stale value.
 
 Communication accounting (exact Python ints, the repo-wide contract):
 
@@ -54,7 +54,6 @@ import jax.numpy as jnp
 
 from repro import comm
 from repro.core import admm
-from repro.core.fednew import _mask_rows
 from repro.core.objectives import ClientDataset, Objective
 from repro.core.quantization import (
     exact_payload_bits,
@@ -176,18 +175,18 @@ def step(
     codec = cfg.build_codec()
     if codec.needs_rng:
         key, sub = jax.random.split(state.key)
-        keys = comm.client_keys(sub, n_local, axis_name, n_global_clients)
     else:
-        key, keys = state.key, None
-    wire = codec.encode(keys, corr, state.comm, state.step)
-    corr_tx = codec.decode(wire, state.comm, state.step)
-    comm_state = codec.update_state(corr_tx, corr, state.comm, state.step)
+        key, sub = state.key, None
+    corr_tx, comm_state = comm.encode_decode(
+        codec, sub, corr, state.comm, step=state.step, axis_name=axis_name,
+        n_global_clients=n_global_clients,
+    )
 
     hest = state.hest + cfg.alpha * corr_tx.reshape(n_local, d, d)
     if mask is not None:
         # Offline clients sent nothing: estimate and codec state stay stale.
-        hest = _mask_rows(mask, hest, state.hest)
-        comm_state = _mask_rows(mask, comm_state, state.comm)
+        hest = admm.mask_client_rows(mask, hest, state.hest)
+        comm_state = admm.mask_client_rows(mask, comm_state, state.comm)
 
     # -- PS side: mean of ALL estimates (the PS holds stale ones too) -------
     Hbar = admm.tree_mean_clients(hest, axis_name)

@@ -37,7 +37,6 @@ import jax
 import jax.numpy as jnp
 import jax.scipy.linalg as jsl
 
-from repro import comm
 from repro.core import admm, fednew, hvp
 from repro.core.objectives import ClientDataset, Objective, is_param_tree
 
@@ -172,48 +171,22 @@ def step(
     (nobody sampled, buffer below K) is a frozen no-op on every carried
     field but the clocks — the conformance freeze contract."""
     fcfg = cfg.fednew_config()
-    fednew._check_matfree(obj, fcfg)
     if axis_name is not None:
         obj = obj.with_axis(axis_name)
     n_local = state.lam.shape[0]
 
-    # -- client submit phase: identical math to fednew.step's first half ----
-    if fcfg.hessian_period > 0:
-        refresh = (state.step % fcfg.hessian_period) == 0
-        curv = jax.lax.cond(
-            refresh,
-            lambda: fednew._fresh_curv(obj, state.x, data, fcfg, n_local),
-            lambda: state.curv,
-        )
-        if mask is not None:
-            curv = fednew._mask_rows(mask, curv, state.curv)
-    else:
-        curv = state.curv
-
-    g_i = obj.local_grad(state.x, data)
-    rhs = admm.admm_rhs(
-        g_i, state.lam, jnp.broadcast_to(state.y, g_i.shape), fcfg.rho
+    # -- client submit phase: fednew's client half (eq. 9 and the codec) ----
+    curv, _, y_i_tx, comm_state, key, _ = fednew.client_half(
+        state, obj, data, fcfg, axis_name=axis_name,
+        n_global_clients=n_global_clients, mask=mask,
     )
-    y_i = fednew._local_solve(curv, rhs, fcfg, obj, data)
-
-    codec = fcfg.build_codec()
-    if codec.needs_rng:
-        key, sub = jax.random.split(state.key)
-        keys = comm.client_keys(sub, y_i.shape[0], axis_name, n_global_clients)
-    else:
-        key, keys = state.key, None
-    wire = codec.encode(keys, y_i, state.comm, state.step)
-    y_i_tx = codec.decode(wire, state.comm, state.step)
-    comm_state = codec.update_state(y_i_tx, y_i, state.comm, state.step)
-    if mask is not None:
-        comm_state = fednew._mask_rows(mask, comm_state, state.comm)
 
     # -- deposit into the buffer (re-submitting overwrites the stale entry) --
     submit = (
         jnp.ones((n_local,), jnp.float32) if mask is None
         else (mask > 0).astype(jnp.float32)
     )
-    pending = fednew._mask_rows(submit, y_i_tx, state.pending)
+    pending = admm.mask_client_rows(submit, y_i_tx, state.pending)
     pending_mask = jnp.maximum(state.pending_mask, submit)
     submit_step = jnp.where(
         submit > 0, jnp.broadcast_to(state.step, (n_local,)), state.submit_step
@@ -254,7 +227,7 @@ def step(
     )
 
     # -- exact uplink accounting (submission is the transmission) -----------
-    bits = codec.payload_bits_metric(
+    bits = fcfg.build_codec().payload_bits_metric(
         data.dim, fednew.word_bits(y_i_tx), state.step
     )
     if mask is not None:

@@ -45,6 +45,11 @@ def test_resolved_backend_on_cpu(monkeypatch):
     assert dispatch.interpret_flag("pallas-interpret") is True
     assert dispatch.interpret_flag("pallas") is False
     assert dispatch.default_interpret() is True  # CPU
+    # FedNew's eq. 9 takes the kernel when its solve backend says pallas
+    assert fednew.FedNewConfig(solve_backend="pallas").solve_uses_kernel
+    assert not fednew.FedNewConfig(
+        backend="pallas", solve_backend="reference").solve_uses_kernel
+    assert not fednew.FedNewConfig().solve_uses_kernel
 
 
 def test_env_override_resolves_auto(monkeypatch):
@@ -199,19 +204,6 @@ def test_get_solver_accepts_backend(problem):
                             quant_backend="pallas")
     _, m = engine.run(sol, obj, data, 2, key=KEY)
     assert jnp.all(m.uplink_bits_per_client == 2 * data.dim + 32)
-
-
-def test_legacy_use_kernel_maps_to_pallas_solve(monkeypatch):
-    monkeypatch.delenv(dispatch.ENV_BACKEND, raising=False)
-    cfg = fednew.FedNewConfig(use_kernel=True)
-    assert cfg.resolved_solve_backend == "pallas"
-    assert cfg.solve_uses_kernel  # interpret on CPU, compiled on TPU
-    # explicit backend beats the legacy flag
-    cfg2 = fednew.FedNewConfig(use_kernel=True, backend="reference")
-    assert cfg2.resolved_solve_backend == "reference"
-    assert not cfg2.solve_uses_kernel
-    # default on CPU: auto -> reference (no silent interpreter)
-    assert not fednew.FedNewConfig().solve_uses_kernel
 
 
 # ---------------------------------------------------------------------------

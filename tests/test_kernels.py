@@ -226,7 +226,8 @@ def test_clients_per_step_fits_vmem_budget():
 
 
 def test_fednew_with_kernel_path_matches_cholesky():
-    """End-to-end: FedNew rounds with use_kernel=True track the faithful path."""
+    """End-to-end: FedNew rounds on the Pallas eq. 9 kernel track the
+    faithful path."""
     from repro.core import fednew
     from repro.core.objectives import logistic_regression
     from repro.data.synthetic import PAPER_DATASETS, make_dataset
@@ -234,7 +235,8 @@ def test_fednew_with_kernel_path_matches_cholesky():
     data = make_dataset(PAPER_DATASETS["phishing"], jax.random.PRNGKey(0))
     obj = logistic_regression(mu=1e-3)
     cfg_ref = fednew.FedNewConfig(rho=1.0, alpha=1.0, hessian_period=1)
-    cfg_ker = fednew.FedNewConfig(rho=1.0, alpha=1.0, hessian_period=1, use_kernel=True)
+    cfg_ker = fednew.FedNewConfig(rho=1.0, alpha=1.0, hessian_period=1,
+                                  solve_backend="pallas")
     _, m_ref = fednew.run(obj, data, cfg_ref, rounds=8)
     _, m_ker = fednew.run(obj, data, cfg_ker, rounds=8)
     np.testing.assert_allclose(

@@ -305,8 +305,6 @@ def test_config_validation():
         fednew.FedNewConfig(hessian_repr="matfree", cg_iters=0)
     with pytest.raises(ValueError, match="cg_tol"):
         fednew.FedNewConfig(hessian_repr="matfree", cg_tol=-1.0)
-    with pytest.raises(ValueError, match="matfree"):
-        fednew.FedNewConfig(hessian_repr="matfree", use_kernel=True)
     # solve_backend="pallas" on matfree routes the HVPs to the glm_hvp kernel
     cfg = fednew.FedNewConfig(hessian_repr="matfree", solve_backend="pallas")
     assert not cfg.solve_uses_kernel  # no dense Hessians are cached
