@@ -8,13 +8,27 @@ State layout mirrors Algorithm 1:
            hessian_repr="dense"   (n, d, d) cached Cholesky factors of
                                   (H_i + (alpha+rho) I) (reference solve),
                                   or the raw H_i (Pallas CG kernel)
-           hessian_repr="matfree" (n, d) per-client Hessian *anchor points* —
-                                  the iterate each client's curvature is
-                                  evaluated at; no d x d array ever exists
+           hessian_repr="matfree" (n, m) for an objective with the GLM split
+                                  (``Objective.local_curvature``): each
+                                  client's curvature weights at its anchor,
+                                  the iterate it last refreshed at;
+                                  (n, d) otherwise: the anchor points
+                                  themselves. No d x d array ever exists
   comm   (n, w)    per-client compression-codec state (``repro.comm``): the
                    previously-quantized vector for stoch_quant (Q-FedNew's
                    ŷ), the error-feedback residual for topk, width 0 for the
                    identity codec
+  grad_x (n, d)    every client's local gradient at x, computed by the round
+                   that produced x (its evaluation; ``init`` at x^0)
+  curv_x (n, m)    matfree GLM: every client's curvature weights at x, from
+                   the same evaluation; width 0 on every other path
+
+The round's evaluation at x^{k+1} computes the margins A x^{k+1} and the
+per-client gradients anyway (the loss and ``grad_norm``); carrying them in
+grad_x / curv_x lets round k+1 start from them instead of sweeping the
+features again at the same point. One function makes both: the evaluation
+traces it, and ``carry_at``, its compiled form, serves ``init`` and the
+streamed-cohort runtime.
 
 One step serves both layouts of ``x``: a model's param pytree
 (``objectives.from_loss_fn``; ``hessian_repr="matfree"``, one device) or
@@ -40,9 +54,11 @@ the computation-efficient "zeroth Hessian" variant, one factorization ever).
   "matfree" never build H_i: solve with damped conjugate gradients
             (``hvp.cg_solve_clients``) where each matvec is the objective's
             closed-form batched HVP (``Objective.local_hvp``) at the cached
-            per-client anchor, or, for a GLM objective on the Pallas solve
-            backend, the ``glm_hvp`` kernel (one read of the features per
-            matvec). O(n d) state, O(cg_iters n m d) compute — the
+            per-client anchor, or, for a GLM objective, A^T (w (A v)) / m +
+            l2 v with the cached weights w: the ``glm_hvp`` kernel (one
+            read of the features per matvec) where the solve backend
+            resolves to Pallas, its jnp reference elsewhere. O(n d) state,
+            O(cg_iters n m d) compute — the
             only path that survives d ~ 1e5+. ``cg_iters``/``cg_tol`` bound
             the inner iteration; run to convergence (tol ~ 1e-7, generous
             iters) the trajectory matches "dense" to solver tolerance.
@@ -193,6 +209,8 @@ class FedNewState(NamedTuple):
     comm: jax.Array  # per-client codec state (ŷ / EF residual / width 0)
     key: jax.Array
     step: jax.Array
+    grad_x: jax.Array  # per-client local gradients at x
+    curv_x: jax.Array  # per-client GLM weights at x; width 0 off matfree GLM
 
 
 class StepMetrics(NamedTuple):
@@ -222,8 +240,11 @@ class StepMetricsDiag(NamedTuple):
                           0 on the dense paths
     codec_error           mean_i ||decode(encode(y_i)) - y_i|| / ||y_i||
                           (exact compression error of the uplink codec)
-    anchor_staleness      matfree: mean_i ||anchor_i - x^k|| (drift of the
-                          cached curvature anchors); dense: rounds since
+    anchor_staleness      matfree without the GLM split: mean_i
+                          ||anchor_i - x^k|| (drift of the cached
+                          curvature anchors); dense, and matfree GLM (whose
+                          cache holds weights, not anchors, and keeps no
+                          anchor to measure without a sweep): rounds since
                           this round's Hessian refresh
     """
 
@@ -252,12 +273,13 @@ def _diag_mean(values, mask, axis_name):
     return total / jnp.maximum(count, 1.0)
 
 
-def _anchor_staleness(state, curv, cfg: FedNewConfig, mask, axis_name):
-    """Hessian-anchor staleness: matfree measures the anchors' actual drift
-    from the current iterate; dense reports rounds since the refresh that
-    produced this round's factors (a host-free re-derivation of the
+def _anchor_staleness(state, curv, cfg: FedNewConfig, obj, mask, axis_name):
+    """Hessian-anchor staleness: where the cache holds anchors (matfree
+    without the GLM split) it measures their actual drift from the current
+    iterate; elsewhere it reports rounds since the refresh that produced
+    this round's cache (a host-free re-derivation of the
     ``step % hessian_period`` schedule)."""
-    if cfg.matfree:
+    if cfg.matfree and not _glm_split(obj, cfg):
         bcast = jax.tree.map(
             lambda xl, cl: cl - jnp.broadcast_to(xl, cl.shape), state.x, curv
         )
@@ -272,6 +294,7 @@ def _anchor_staleness(state, curv, cfg: FedNewConfig, mask, axis_name):
 def _diag_metrics(
     state: FedNewState,
     cfg: FedNewConfig,
+    obj: Objective,
     base: StepMetrics,
     *,
     y_i,
@@ -315,7 +338,7 @@ def _diag_metrics(
         diag_cg_residual=cg_residual,
         diag_codec_error=codec_err,
         diag_anchor_staleness=_anchor_staleness(
-            state, curv, cfg, mask, axis_name
+            state, curv, cfg, obj, mask, axis_name
         ),
     )
 
@@ -340,12 +363,48 @@ def _check_matfree(obj: Objective, cfg: FedNewConfig) -> None:
         )
 
 
-def _fresh_curv(obj: Objective, x, data, cfg: FedNewConfig, n_local: int):
+def _glm_split(obj: Objective, cfg: FedNewConfig) -> bool:
+    """Static: the matfree cache holds GLM curvature weights, not anchors."""
+    return cfg.matfree and obj.has_curvature
+
+
+def _fresh_curv(obj: Objective, x, curv_x, data, cfg: FedNewConfig,
+                n_local: int):
     """The curvature cache a client that saw iterate ``x`` would hold:
-    factors/Hessians in dense mode, the anchor point itself in matfree."""
-    if cfg.matfree:
-        return admm.bcast_clients(x, n_local)
-    return _factorize(obj, x, data, cfg)
+    factors/Hessians in dense mode; in matfree mode its GLM weights at
+    ``x`` where the objective has the split (``curv_x``, which a state at
+    ``x`` already carries), else the anchor point itself."""
+    if not cfg.matfree:
+        return _factorize(obj, x, data, cfg)
+    if obj.has_curvature:
+        return curv_x
+    return admm.bcast_clients(x, n_local)
+
+
+def _carry(obj: Objective, x, data, glm: bool):
+    grad_x = obj.local_grad(x, data)
+    if glm:
+        curv_x = obj.local_curvature(x, data)
+    else:
+        curv_x = jnp.zeros(
+            (data.n_clients, 0), jax.tree.leaves(grad_x)[0].dtype
+        )
+    return grad_x, curv_x
+
+
+_carry_jit = jax.jit(_carry, static_argnums=(0, 3))
+
+
+def carry_at(obj: Objective, x, data, cfg: FedNewConfig):
+    """``(grad_x, curv_x)``: every client's local gradient at ``x`` and,
+    in matfree GLM mode, its curvature weights there (an ``(n, 0)``
+    placeholder on every other path): what a state at ``x`` carries. Each
+    round's evaluation makes them at x^{k+1} (where the weights come from
+    the same margins A x as the loss and the gradient, so the round reads
+    the features once for all three); this compiled form makes them where
+    a state is built outside a round (``init`` at x^0, a streamed cohort's
+    rows), so those hold the bits a round's evaluation would."""
+    return _carry_jit(obj, x, data, _glm_split(obj, cfg))
 
 
 def _check_tree_mode(cfg: FedNewConfig, axis_name=None) -> None:
@@ -376,44 +435,41 @@ def init(
         dtype = data.features.dtype if data.features.dtype in (jnp.float32, jnp.float64) else jnp.float32
         x = jnp.zeros((data.dim,), dtype) if x0 is None else jnp.asarray(x0, dtype)
     n = data.n_clients
+    grad_x, curv_x = carry_at(obj, x, data, cfg)
     return FedNewState(
         x=x,
         y=jax.tree.map(jnp.zeros_like, x),
         lam=admm.stack_zeros(x, n),
-        curv=_fresh_curv(obj, x, data, cfg, n),
+        curv=_fresh_curv(obj, x, curv_x, data, cfg, n),
         comm=comm.init_state_tree(cfg.build_codec(), n, x),
         key=key,
         step=jnp.zeros((), jnp.int32),
+        grad_x=grad_x,
+        curv_x=curv_x,
     )
 
 
 def _matfree_matvec(curv, cfg: FedNewConfig, obj: Objective, data):
-    """The matfree CG's matvec: every client's H_i at its anchor in ``curv``.
+    """The matfree CG's matvec: every client's H_i as cached in ``curv``.
 
-    An objective that exposes its GLM split (``local_curvature``) on a solve
-    backend that resolves to Pallas gets the ``glm_hvp`` kernel, which reads
-    the features once per call where ``local_hvp``'s two matvecs read them
-    twice. The kernel's copy of the features is made here, outside the CG
-    loop (XLA hoists it out of a scan block too, as the features never
+    An objective that exposes its GLM split (``local_curvature``) caches
+    each client's weights w_i, so the matvec is A_i^T (w_i (A_i v_i)) / m +
+    l2 v_i with no product at the anchor left to form: the ``glm_hvp``
+    kernel where the solve backend resolves to Pallas (one read of the
+    features per call), its jnp reference (``kernels/glm_hvp/ref.py``)
+    elsewhere. The kernel's copy of the features is made here, outside the
+    CG loop (XLA hoists it out of a scan block too, as the features never
     change); it is bf16 only where the default matmul precision would hand
     the chip bf16 operands anyway (``dispatch.matmul_operand_dtype``).
-    Every other case calls ``local_hvp``, lowered as it always was."""
-    backend = cfg.resolved_solve_backend
-    if not (obj.has_curvature
-            and dispatch.use_pallas(dispatch.resolve_backend(backend))):
+    Objectives without the split call ``local_hvp`` at the cached
+    anchors."""
+    if not obj.has_curvature:
         return lambda v: obj.local_hvp(curv, data, v)
-    feats = data.features
-    a = feats.astype(dispatch.matmul_operand_dtype(feats.dtype))
-
-    def matvec(v):
-        # The weights are formed per call, as local_hvp forms them: XLA
-        # hoists their A · anchor product out of the CG loop and merges it
-        # with the gradient's A x. Formed before the loop, that product
-        # compiles on the TPU as a sweep of its own.
-        w = obj.local_curvature(curv, data)
-        return dispatch.glm_hvp(a, w, v, mu=obj.l2, backend=backend)
-
-    return matvec
+    backend = cfg.resolved_solve_backend
+    a = data.features
+    if dispatch.use_pallas(dispatch.resolve_backend(backend)):
+        a = a.astype(dispatch.matmul_operand_dtype(a.dtype))
+    return lambda v: dispatch.glm_hvp(a, curv, v, mu=obj.l2, backend=backend)
 
 
 def _local_solve(curv, rhs, cfg: FedNewConfig, obj=None, data=None,
@@ -459,11 +515,13 @@ def client_half(
     """The clients' half of Algorithm 1: refresh the curvature, solve eq. 9
     and pass the direction through the uplink codec; nothing crosses
     clients. ``obj`` is already bound to ``axis_name``; ``state`` is any
-    state with FedNew's x/y/lam/curv/comm/key/step fields (the async
-    solver's buffer state too). Returns ``(curv, y_i, y_i_tx, comm, key,
-    cg_info)``: the refreshed curvature, the eq. 9 directions, their PS-side
-    reconstruction, the advanced codec state, the run key after this
-    round's split, and the CG result (matfree with diagnostics; else
+    state with FedNew's x/y/lam/curv/comm/key/step/grad_x/curv_x fields
+    (the async solver's buffer state too): the round starts from the
+    gradients and GLM weights at x that the state carries and does not
+    evaluate them again. Returns ``(curv, y_i, y_i_tx, comm, key,
+    cg_info)``: the refreshed curvature, the eq. 9 directions, their
+    PS-side reconstruction, the advanced codec state, the run key after
+    this round's split, and the CG result (matfree with diagnostics; else
     None)."""
     _check_matfree(obj, cfg)
     n_local = jax.tree.leaves(state.lam)[0].shape[0]
@@ -473,7 +531,8 @@ def client_half(
             refresh = (state.step % cfg.hessian_period) == 0
             curv = jax.lax.cond(
                 refresh,
-                lambda: _fresh_curv(obj, state.x, data, cfg, n_local),
+                lambda: _fresh_curv(obj, state.x, state.curv_x, data, cfg,
+                                    n_local),
                 lambda: state.curv,
             )
             if mask is not None:
@@ -483,7 +542,7 @@ def client_half(
         curv = state.curv
 
     with jax.named_scope("fednew.grad"):
-        g_i = obj.local_grad(state.x, data)  # (n, ...) — never transmitted
+        g_i = state.grad_x  # (n, ...) — never transmitted
         rhs = admm.admm_rhs(
             g_i, state.lam, admm.bcast_clients(state.y, n_local), cfg.rho
         )
@@ -589,22 +648,26 @@ def step(
 
         x = jax.tree.map(lambda p, yl: p - yl, state.x, y)  # eq. 14
 
-    new_state = FedNewState(
-        x=x, y=y, lam=lam, curv=curv, comm=comm_state, key=key,
-        step=state.step + 1,
-    )
     norm = jnp.linalg.norm if flat else hvp.tree_norm
     with jax.named_scope("fednew.eval"):
+        # The gradients and GLM weights at x^{k+1} serve this round's
+        # metrics and ride in the state to the next round, which would
+        # otherwise sweep the features again at the same point.
+        grad_x, curv_x = _carry(obj, x, data, _glm_split(obj, cfg))
+        new_state = FedNewState(
+            x=x, y=y, lam=lam, curv=curv, comm=comm_state, key=key,
+            step=state.step + 1, grad_x=grad_x, curv_x=curv_x,
+        )
         metrics = StepMetrics(
             loss=obj.global_loss(x, data),
-            grad_norm=norm(obj.global_grad(x, data)),
+            grad_norm=norm(obj._agg(grad_x)),
             uplink_bits_per_client=bits,
             dual_sum_residual=admm.dual_sum_residual(lam, axis_name),
             direction_norm=norm(y),
         )
         if cfg.diagnostics:
             metrics = _diag_metrics(
-                state, cfg, metrics, y_i=y_i, y_i_tx=y_i_tx, y=y, curv=curv,
+                state, cfg, obj, metrics, y_i=y_i, y_i_tx=y_i_tx, y=y, curv=curv,
                 cg_info=cg_info, mask=mask, axis_name=axis_name,
             )
     return new_state, metrics
@@ -625,7 +688,7 @@ def solver(cfg: FedNewConfig):
         name=name,
         init=lambda obj, data, key, x0=None: init(obj, data, cfg, key, x0),
         step=lambda state, obj, data, **axis_kw: step(state, obj, data, cfg, **axis_kw),
-        client_fields=("lam", "curv", "comm"),
+        client_fields=("lam", "curv", "comm", "grad_x", "curv_x"),
     )
 
 

@@ -116,11 +116,16 @@ class Objective:
     when an objective doesn't provide one.
 
     ``local_curvature`` is the split a generalized linear model's HVP has
-    (flat layout only): per-row weights ``w`` at the same per-client
-    anchors, such that ``local_hvp(x, data, v)_i == A_i^T (w_i * (A_i v_i))
-    / m + l2 * v_i`` with ``A = data.features`` of ``m`` rows a client. A
-    solver that finds it may apply every client's Hessian in one read of
-    the features (the ``glm_hvp`` kernel) instead of calling ``local_hvp``.
+    (flat layout only): every client's per-row weights ``w`` at one point
+    ``x``, such that ``local_hvp(anchors, data, v)_i == A_i^T (w_i * (A_i
+    v_i)) / m + l2 * v_i`` where every anchor is ``x``, with ``A =
+    data.features`` of ``m`` rows a client. It takes ``x`` as
+    ``local_grad`` does, so its margins ``A x`` are the product the loss
+    and the gradient at ``x`` form, and a program that evaluates all three
+    reads the features once. A solver that finds the split may cache each
+    client's weights at its anchor and apply every client's Hessian in one
+    read of the features (the ``glm_hvp`` kernel) instead of calling
+    ``local_hvp``.
 
     ``local_hessian`` is optional: autodiff model objectives
     (:func:`from_loss_fn`) cannot materialize (d, d) blocks, so solvers on
@@ -242,7 +247,7 @@ def logistic_regression(mu: float = 1e-3) -> Objective:
     hess = jax.vmap(partial(_logreg_hessian_1, mu=mu), in_axes=(None, 0, 0))
     # hvp maps per-client anchors AND per-client vectors (see Objective doc)
     hvp = jax.vmap(partial(_logreg_hvp_1, mu=mu), in_axes=(0, 0, 0, 0))
-    curvature = jax.vmap(_logreg_curvature_1, in_axes=(0, 0, 0))
+    curvature = jax.vmap(_logreg_curvature_1, in_axes=(None, 0, 0))
     return Objective(
         local_loss=lambda x, d: loss(x, d.features, d.labels),
         local_grad=lambda x, d: grad(x, d.features, d.labels),

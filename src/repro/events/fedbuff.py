@@ -108,6 +108,8 @@ class FedBuffState(NamedTuple):
     submit_step: jax.Array  # (n,) int32 server step each buffer entry saw
     key: jax.Array
     step: jax.Array
+    grad_x: jax.Array  # (n, d) per-client local gradients at x
+    curv_x: jax.Array  # per-client GLM weights at x (fednew layouts)
 
 
 class AsyncStepMetrics(NamedTuple):
@@ -152,6 +154,8 @@ def init(
         submit_step=jnp.zeros((n,), jnp.int32),
         key=base.key,
         step=base.step,
+        grad_x=base.grad_x,
+        curv_x=base.curv_x,
     )
 
 
@@ -235,17 +239,20 @@ def step(
 
         bits = participation.masked_bits_metric(bits, mask, axis_name)
 
+    # The gradients and GLM weights at the (possibly unmoved) iterate serve
+    # the metrics and the next round's client half, as in fednew.step.
+    grad_x, curv_x = fednew._carry(obj, x, data, fednew._glm_split(obj, fcfg))
     new_state = FedBuffState(
         x=x, y=y, lam=lam, curv=curv, comm=comm_state, pending=pending,
         pending_mask=pending_mask, submit_step=submit_step, key=key,
-        step=state.step + 1,
+        step=state.step + 1, grad_x=grad_x, curv_x=curv_x,
     )
     occupancy = jnp.sum(pending_mask)
     if axis_name is not None:
         occupancy = jax.lax.psum(occupancy, axis_name)
     metrics = AsyncStepMetrics(
         loss=obj.global_loss(x, data),
-        grad_norm=jnp.linalg.norm(obj.global_grad(x, data)),
+        grad_norm=jnp.linalg.norm(obj._agg(grad_x)),
         uplink_bits_per_client=bits,
         dual_sum_residual=admm.dual_sum_residual(lam, axis_name),
         direction_norm=jnp.linalg.norm(applied),
@@ -274,7 +281,8 @@ def solver(cfg: FedNewAsyncConfig):
             state, obj, data, cfg, **axis_kw
         ),
         client_fields=(
-            "lam", "curv", "comm", "pending", "pending_mask", "submit_step"
+            "lam", "curv", "comm", "pending", "pending_mask", "submit_step",
+            "grad_x", "curv_x",
         ),
     )
 

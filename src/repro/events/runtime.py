@@ -341,6 +341,12 @@ def _barrier_run(
         ids = (np.arange(cohort, dtype=np.int64) + r * cohort) % n
         data = data0 if r == 0 else source.materialize(ids)
         lam_rows, comm_rows = cache.gather(ids)
+        # A state carries every client's gradient (and matfree GLM weights)
+        # at x: a new cohort's come from its own rows, as init makes them.
+        grad_x, curv_x = (
+            (state.grad_x, state.curv_x) if r == 0
+            else fednew.carry_at(obj, x, data, fcfg)
+        )
         st = fednew.FedNewState(
             x=x,
             y=y,
@@ -352,6 +358,8 @@ def _barrier_run(
             comm=jnp.asarray(comm_rows),
             key=k,
             step=jnp.asarray(r, jnp.int32),
+            grad_x=grad_x,
+            curv_x=curv_x,
         )
         with _span(tracer, "dispatch", label="barrier_step", rounds=1):
             st2, m = jstep(st, data)

@@ -10,12 +10,20 @@ Acceptance contract of the matfree PR:
     loss gap at the paper's d=267, under BOTH the scan and the shard_map
     schedule (CG run to convergence on the well-damped system);
   * a d=1e5 logreg round runs on CPU without materializing any (n, d, d)
-    array — per-client state is O(d) (the curv cache holds anchor points);
+    array — per-client state is O(d) (the curv cache holds each client's
+    GLM weights, O(m));
+  * the state carries every client's gradient and GLM weights at x, made
+    by the evaluation of the round that produced x, so a round reads the
+    f32 features twice outside the CG loop (A x and A^T r at x^{k+1});
   * the dense default stays the default and the new knobs round-trip
     through the declarative spec layer.
 """
 
 import json
+import os
+import re
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -76,18 +84,19 @@ def test_logreg_hvp_honors_per_client_anchors(logreg_267):
 
 
 def test_logreg_curvature_split_is_its_hvp(logreg_267):
-    """local_curvature's weights rebuild local_hvp: A^T (w * (A v)) / m +
-    l2 v at per-client anchors; the objectives that have no such split
+    """local_curvature's weights at x rebuild local_hvp at anchors x:
+    A^T (w * (A v)) / m + l2 v; the objectives that have no such split
     say so."""
     obj, data = logreg_267
     n, m = data.n_clients, data.features.shape[1]
-    x = 0.1 * jax.random.normal(jax.random.PRNGKey(1), (n, D))
+    x = 0.1 * jax.random.normal(jax.random.PRNGKey(1), (D,))
     v = jax.random.normal(jax.random.PRNGKey(2), (n, D))
     w = obj.local_curvature(x, data)
     assert w.shape == (n, m) and obj.l2 == 1e-3
     A = data.features
     split = jnp.einsum("nmd,nm->nd", A, w * jnp.einsum("nmd,nd->nm", A, v)) / m + obj.l2 * v
-    np.testing.assert_allclose(split, obj.local_hvp(x, data, v), rtol=1e-5, atol=1e-6)
+    anchors = jnp.broadcast_to(x, (n, D))
+    np.testing.assert_allclose(split, obj.local_hvp(anchors, data, v), rtol=1e-5, atol=1e-6)
     assert obj.has_curvature and not quadratic().has_curvature
 
 
@@ -203,9 +212,10 @@ def test_matfree_glm_kernel_matches_reference(mesh_devices, d):
     assert np.linalg.norm(got_x - ref_x) <= 1e-5 * np.linalg.norm(ref_x)
 
 
-def test_matfree_auto_on_cpu_lowers_local_hvp(logreg_267):
-    """backend="auto" on the CPU keeps the local_hvp path, lowered exactly
-    as "reference" lowers it: no glm_hvp kernel in the program."""
+def test_matfree_auto_on_cpu_lowers_reference(logreg_267):
+    """backend="auto" on the CPU keeps the jnp HVP (the glm_hvp reference,
+    local_hvp's expression on the cached weights), lowered exactly as
+    "reference" lowers it: no glm_hvp kernel in the program."""
     obj, data = logreg_267
 
     def lowered(**kw):
@@ -229,7 +239,8 @@ def test_matfree_qfednew_and_hessian_period(logreg_267):
         cg_iters=100, cg_tol=1e-7, hessian_period=0,
     )
     state = fednew.init(obj, data, cfg, KEY)
-    assert state.curv.shape == (data.n_clients, D)  # anchors, not factors
+    rows = data.features.shape[1]
+    assert state.curv.shape == (data.n_clients, rows)  # GLM weights, not factors
     anchor0 = state.curv
     for _ in range(3):
         state, m = jax.jit(
@@ -268,6 +279,162 @@ def test_matfree_partial_participation_freezes_anchors(logreg_267):
     assert np.isfinite(float(m.loss))
 
 
+def test_matfree_masked_refresh_keeps_weights_at_last_anchor(logreg_267):
+    """Partial participation over several rounds with x moving: a sampled
+    client's row of the GLM cache is the previous state's curv_x (its
+    weights at the x it saw), and every row is local_curvature at the x of
+    the last round its client was sampled in (x^0 before its first)."""
+    obj, data = logreg_267
+    n = data.n_clients
+    cfg = fednew.FedNewConfig(**{**MATFREE_HP, "cg_iters": 8})
+    step = jax.jit(lambda s, mk: fednew.step(s, obj, data, cfg, mask=mk))
+    state = init = fednew.init(obj, data, cfg, KEY)
+    anchor = [state.x] * n
+    masks = [[1, 0, 0, 1, 0, 0, 1, 0], [0, 1, 0, 0, 1, 0, 0, 0],
+             [1, 1, 0, 0, 0, 0, 0, 1], [0, 0, 0, 1, 1, 0, 0, 0]]
+    for mk in masks:
+        new, _ = step(state, jnp.asarray(mk, jnp.float32))
+        assert not np.allclose(np.asarray(new.x), np.asarray(state.x))
+        for i in range(n):
+            if mk[i]:
+                np.testing.assert_array_equal(np.asarray(new.curv[i]),
+                                              np.asarray(state.curv_x[i]))
+                anchor[i] = state.x
+            want = np.asarray(obj.local_curvature(anchor[i], data)[i])
+            got = np.asarray(new.curv[i])
+            assert np.max(np.abs(got - want)) <= 1e-5 * np.max(np.abs(want))
+        state = new
+    # clients 2 and 5 are never sampled: their rows are init's, bit for bit
+    for i in (2, 5):
+        np.testing.assert_array_equal(np.asarray(state.curv[i]),
+                                      np.asarray(init.curv[i]))
+
+
+# ---------------------------------------------------------------------------
+# the carry: gradients and GLM weights at x, made once per iterate
+# ---------------------------------------------------------------------------
+
+
+def _carry_errors(obj, data, hp, mode, mesh=None):
+    """Worst relative error, over rounds 0..3, of the carried grad_x /
+    curv_x against the oracles at the state's x, and of the cache against
+    local_curvature at the anchor it was refreshed at (x of the round
+    before, hessian_period = 1)."""
+    solver = engine.get_solver("fednew", **hp)
+    states = [solver.init(obj, data, KEY)]
+    for rounds in (1, 2, 3):
+        st, _ = engine.run(solver, obj, data, rounds, key=KEY, mode=mode,
+                           mesh=mesh, block_size=2)
+        states.append(st)
+
+    def rel(got, want):
+        got, want = np.asarray(got), np.asarray(want)
+        assert got.shape == want.shape
+        return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+
+    errs = {"grad_x": 0.0, "curv_x": 0.0, "curv": 0.0}
+    for prev, st in zip([states[0]] + states, states):
+        errs["grad_x"] = max(errs["grad_x"],
+                             rel(st.grad_x, obj.local_grad(st.x, data)))
+        errs["curv_x"] = max(errs["curv_x"],
+                             rel(st.curv_x, obj.local_curvature(st.x, data)))
+        errs["curv"] = max(errs["curv"],
+                           rel(st.curv, obj.local_curvature(prev.x, data)))
+    return errs
+
+
+@pytest.mark.parametrize("mode", ["scan", "host"])
+def test_matfree_carry_matches_oracles(logreg_267, mode):
+    """GLM matfree: after every round the state's grad_x and curv_x are the
+    oracles at its x, and curv holds local_curvature at the refreshed
+    anchors."""
+    obj, data = logreg_267
+    errs = _carry_errors(obj, data, dict(MATFREE_HP, cg_iters=8), mode)
+    assert max(errs.values()) <= 1e-5, errs
+
+
+SHARDED_CARRY = """
+import json
+import jax
+import test_matfree as tm
+from repro.core.objectives import logistic_regression
+from repro.data import synthetic
+from repro.launch.mesh import make_client_mesh
+
+spec = synthetic.DatasetSpec("custom", n_clients=8, samples_per_client=64,
+                             dim=tm.D, sparse=True)
+data = synthetic.make_dataset(spec, tm.KEY)
+obj = logistic_regression(1e-3)
+mesh = make_client_mesh(8)
+hp = dict(tm.MATFREE_HP, cg_iters=8)
+errs = tm._carry_errors(obj, data, hp, "scan", mesh=mesh)
+dense = tm.engine.get_solver("fednew", **tm.DENSE_HP)
+st, _ = tm.engine.run(dense, obj, data, 3, key=tm.KEY, mesh=mesh, block_size=2)
+g = obj.local_grad(st.x, data)
+errs["dense_grad_x"] = float(abs(st.grad_x - g).max() / abs(g).max())
+print(json.dumps({"devices": len(jax.devices()), **errs}))
+"""
+
+
+def test_matfree_carry_under_8_device_shard_map():
+    """The carry under the client-mesh driver on eight virtual devices (a
+    process of its own: the device count is fixed when JAX starts): each
+    device's rows of grad_x / curv_x / curv are its own clients' oracles,
+    for the GLM matfree and the dense path."""
+    tests = os.path.dirname(os.path.abspath(__file__))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join(
+                   [os.path.join(os.path.dirname(tests), "src"), tests]),
+               XLA_FLAGS="--xla_force_host_platform_device_count=8")
+    p = subprocess.run([sys.executable, "-c", SHARDED_CARRY], env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    out = json.loads(p.stdout.strip().splitlines()[-1])
+    assert out.pop("devices") == 8
+    assert max(out.values()) <= 1e-5, out
+
+
+def _feature_consumers(hlo_text: str) -> list:
+    """Instructions of the ENTRY computation that take the features
+    parameter as an operand, leaving out the tuple that hands it to a
+    loop: the reads of the features outside every loop body."""
+    entry = hlo_text[hlo_text.index("\nENTRY") + 1:]
+    lines = entry.splitlines()[1:]
+    param = next(
+        l.split("=")[0].strip().lstrip("%") for l in lines
+        if 'op_name="d.features"' in l and " parameter(" in l
+    )
+    ref = re.compile(r"%" + re.escape(param) + r"\b")
+    out = []
+    for l in lines:
+        if "=" not in l:
+            continue
+        name, rhs = l.split("=", 1)
+        opcode = re.search(r"\b([a-z][\w\-]*)\(", rhs).group(1)
+        if ref.search(rhs) and opcode not in ("tuple", "parameter"):
+            out.append(name.strip().lstrip("%"))
+    return out
+
+
+def test_matfree_round_reads_features_twice_outside_cg():
+    """The compiled matfree round on the reference backend reads the f32
+    features outside the CG while loop exactly twice: A x^{k+1} (the loss,
+    the gradient and the GLM weights share it) and A^T r^{k+1}. The
+    gradient and weights at x^k come from the state, not from two more
+    sweeps at a point the last round already evaluated."""
+    spec = synthetic.DatasetSpec(
+        "custom", n_clients=4, samples_per_client=37, dim=300, sparse=True
+    )
+    obj, data = logistic_regression(1e-3), synthetic.make_dataset(spec, KEY)
+    cfg = fednew.FedNewConfig(rho=1.0, alpha=1.0, hessian_repr="matfree",
+                              cg_iters=4, solve_backend="reference")
+    state = fednew.init(obj, data, cfg, KEY)
+    step = jax.jit(lambda s, d: fednew.step(s, obj, d, cfg))
+    text = step.lower(state, data).compile().as_text()
+    assert " while(" in text
+    assert len(_feature_consumers(text)) == 2, _feature_consumers(text)
+
+
 # ---------------------------------------------------------------------------
 # large d: the only path that survives (acceptance)
 # ---------------------------------------------------------------------------
@@ -287,7 +454,9 @@ def test_matfree_runs_d_1e5_without_dense_hessians(tmp_path):
         key=jax.random.PRNGKey(spec.seed),
         block_size=spec.schedule.block_size,
     )
-    assert state.curv.shape == (4, 100_000)  # O(n d): anchors, no factors
+    # O(n d): gradients and GLM weights, no factors
+    assert state.grad_x.shape == (4, 100_000)
+    assert state.curv.shape == state.curv_x.shape == data.features.shape[:2]
     assert all(np.isfinite(np.asarray(metrics.loss)))
     # and the loss actually moves — these are real Newton-type rounds
     assert metrics.loss[-1] < metrics.loss[0]
